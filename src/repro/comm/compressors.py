@@ -35,7 +35,6 @@ from repro.comm import flat as cflat
 from repro.comm.flat import FlatSpec
 from repro.configs.base import CommConfig
 
-from repro.kernels import INTERPRET as _INTERPRET
 
 Payload = Dict[str, jnp.ndarray]
 
@@ -221,7 +220,7 @@ class StochasticQuant(Compressor):
         from repro.kernels.quantize import quant_roundtrip_flat
         u = jax.random.uniform(key, flat.shape)
         xhat = quant_roundtrip_flat(flat, u, self._scales(flat),
-                                    qmax=self.qmax, interpret=_INTERPRET)
+                                    qmax=self.qmax)
         return xhat, jnp.zeros((), jnp.float32)
 
     def encode_delta(self, key, theta, start, ef):
@@ -239,8 +238,7 @@ class StochasticQuant(Compressor):
         delta = theta - start + ef
         u = jax.random.uniform(key, delta.shape)
         xhat, resid = uplink_roundtrip_flat(
-            theta, start, ef, u, self._scales(delta), qmax=self.qmax,
-            interpret=_INTERPRET)
+            theta, start, ef, u, self._scales(delta), qmax=self.qmax)
         return xhat, jnp.zeros((), jnp.float32), resid
 
     def roundtrip_batched(self, keys, flat):
@@ -252,8 +250,7 @@ class StochasticQuant(Compressor):
         u = jax.vmap(lambda k: jax.random.uniform(k, flat.shape[1:]))(keys)
         xhat = quant_roundtrip_batched(flat, u,
                                        jax.vmap(self._scales)(flat),
-                                       qmax=self.qmax,
-                                       interpret=_INTERPRET)
+                                       qmax=self.qmax)
         return xhat, jnp.zeros((flat.shape[0],), jnp.float32)
 
     def encode_delta_batched(self, keys, theta, start, ef):
@@ -272,7 +269,7 @@ class StochasticQuant(Compressor):
         u = jax.vmap(lambda k: jax.random.uniform(k, theta.shape[1:]))(keys)
         xhat, resid = uplink_roundtrip_batched(
             theta, start, ef, u, jax.vmap(self._scales)(delta),
-            qmax=self.qmax, interpret=_INTERPRET)
+            qmax=self.qmax)
         return xhat, jnp.zeros((theta.shape[0],), jnp.float32), resid
 
 
@@ -319,7 +316,7 @@ class TopK(Compressor):
             return super().roundtrip(key, flat)
         from repro.kernels.quantize import topk_threshold_flat
         vals = jax.lax.top_k(jnp.abs(flat.reshape(-1)), self.k)[0]
-        xhat = topk_threshold_flat(flat, vals[-1], interpret=_INTERPRET)
+        xhat = topk_threshold_flat(flat, vals[-1])
         return xhat, jnp.zeros((), jnp.float32)
 
     def roundtrip_batched(self, keys, flat):
@@ -329,8 +326,7 @@ class TopK(Compressor):
         vals = jax.vmap(
             lambda f: jax.lax.top_k(jnp.abs(f.reshape(-1)), self.k)[0]
         )(flat)
-        xhat = topk_threshold_batched(flat, vals[:, -1],
-                                      interpret=_INTERPRET)
+        xhat = topk_threshold_batched(flat, vals[:, -1])
         return xhat, jnp.zeros((flat.shape[0],), jnp.float32)
 
 
@@ -374,7 +370,7 @@ class SignSGD(Compressor):
             return super().roundtrip(key, flat)
         from repro.kernels.quantize import sign_roundtrip_flat
         scale = self._scale(flat)
-        xhat = sign_roundtrip_flat(flat, scale, interpret=_INTERPRET)
+        xhat = sign_roundtrip_flat(flat, scale)
         return xhat, scale
 
     def roundtrip_batched(self, keys, flat):
@@ -382,7 +378,7 @@ class SignSGD(Compressor):
             return super().roundtrip_batched(keys, flat)
         from repro.kernels.quantize import sign_roundtrip_batched
         scale = jax.vmap(self._scale)(flat)
-        xhat = sign_roundtrip_batched(flat, scale, interpret=_INTERPRET)
+        xhat = sign_roundtrip_batched(flat, scale)
         return xhat, scale
 
     def server_combine(self, agg, wstat):

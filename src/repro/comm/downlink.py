@@ -28,7 +28,6 @@ from repro.comm.compressors import (Compressor, StochasticQuant,
 from repro.comm.flat import FlatSpec
 from repro.configs.base import CommConfig
 
-from repro.kernels import INTERPRET as _INTERPRET
 
 #: engine state keys owned by this module
 MODEL_KEY = "comm_dn_model"
@@ -82,7 +81,7 @@ def broadcast(comp: Compressor, key, packed_theta: jnp.ndarray,
         u = jax.random.uniform(key, delta.shape)
         new_model, resid = broadcast_roundtrip_flat(
             packed_theta, model_row, ef, u, comp._scales(delta),
-            qmax=comp.qmax, interpret=_INTERPRET)
+            qmax=comp.qmax)
         return new_model, (None if ef_row is None else resid)
     delta = packed_theta - model_row
     if ef_row is not None:
@@ -114,8 +113,7 @@ def broadcast_batched(comp: Compressor, keys, packed_theta: jnp.ndarray,
             lambda k: jax.random.uniform(k, delta.shape[1:]))(keys)
         new_models, resid = broadcast_roundtrip_batched(
             packed_theta, model_rows, ef, u,
-            jax.vmap(comp._scales)(delta), qmax=comp.qmax,
-            interpret=_INTERPRET)
+            jax.vmap(comp._scales)(delta), qmax=comp.qmax)
         return new_models, (None if ef_rows is None else resid)
     return jax.vmap(
         lambda k, m, e: broadcast(comp, k, packed_theta, m, e)

@@ -103,11 +103,17 @@ class ModelConfig:
         rem = self.num_layers % len(self.block_pattern)
         return tuple(self.block_pattern[:rem])
 
+    def with_depth(self, num_layers: int) -> "ModelConfig":
+        """This config at ``num_layers`` layers, rounded down to whole
+        ``block_pattern`` periods (at least one), every width kept."""
+        period = len(self.block_pattern)
+        num_layers = max(num_layers, period) // period * period
+        return dataclasses.replace(self, num_layers=num_layers)
+
     def reduced(self, num_layers: int = 2, d_model: int = 256,
                 max_experts: int = 4) -> "ModelConfig":
         """Smoke-test variant: same family/features, tiny dims."""
-        num_layers = max(num_layers, len(self.block_pattern))
-        num_layers = (num_layers // len(self.block_pattern)) * len(self.block_pattern)
+        num_layers = self.with_depth(num_layers).num_layers
         heads = min(self.num_heads, 4)
         kv = min(self.num_kv_heads, heads)
         if heads % kv:

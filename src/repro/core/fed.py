@@ -102,7 +102,6 @@ from repro.comm.compressors import (make_compressor, make_stream_compressor,
 from repro.configs.base import AGGREGATORS, ATTACKS, FedConfig
 from repro.core import sophia
 from repro.core.gnb import gnb_estimate
-from repro.kernels import INTERPRET as _INTERPRET
 from repro.obs import probes as obs_probes
 from repro.core.schedules import lr_at_round
 from repro.robust import aggregators as robust_agg
@@ -1053,8 +1052,7 @@ class FedEngine:
                     client_rngs[0])
             agg_flat = theta + robust_agg.aggregate_stack(
                 rb, deltas, jnp.ones((C,), jnp.float32),
-                normalize=True, use_pallas=fed.comm.use_pallas,
-                interpret=_INTERPRET)
+                normalize=True, use_pallas=fed.comm.use_pallas)
 
         if packed:
             state = self._apply_aggregate_flat(state, agg_flat)
@@ -1137,8 +1135,7 @@ class FedEngine:
             if robust_on:
                 return robust_agg.aggregate_stack(
                     rb, wires, jnp.ones((S,), jnp.float32),
-                    normalize=True, use_pallas=comm.use_pallas,
-                    interpret=_INTERPRET)
+                    normalize=True, use_pallas=comm.use_pallas)
             return jnp.sum(wires, axis=0) / S
 
         if fed.strategy == "parallel":

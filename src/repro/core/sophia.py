@@ -102,14 +102,12 @@ def sophia_step_flat(theta, m, h, grads, h_hat, do_h_update, *, lr, beta1,
     calls.
     """
     if use_pallas:
-        from repro.kernels import INTERPRET
         from repro.kernels.sophia_update import (sophia_update_batched,
                                                  sophia_update_flat)
         fn = sophia_update_batched if theta.ndim == 3 else sophia_update_flat
         return fn(
             theta, m, h, grads, h_hat, do_h_update, lr, beta1=beta1,
-            beta2=beta2, rho=rho, eps=eps, weight_decay=weight_decay,
-            interpret=INTERPRET)
+            beta2=beta2, rho=rho, eps=eps, weight_decay=weight_decay)
     out_dt = (theta.dtype, m.dtype, h.dtype)
     theta, m, h, grads, h_hat = (x.astype(jnp.float32)
                                  for x in (theta, m, h, grads, h_hat))

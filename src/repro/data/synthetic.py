@@ -7,6 +7,7 @@ A synthetic token stream feeds the LM-family architectures.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -83,10 +84,12 @@ def client_batches(key, x, y, part: np.ndarray, batch_size: int):
 # synthetic token streams for the LM-family architectures
 # --------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
 def make_token_batch(key, num_clients: int, batch: int, seq_len: int,
                      vocab_size: int, num_pos_channels: int = 0):
     """Markov-ish token stream: y_t depends on y_{t-1} through a seeded
-    permutation plus noise — learnable structure for the LM loss."""
+    permutation plus noise — learnable structure for the LM loss.
+    One compiled scan over positions, built on the default device."""
     kperm, kinit, knoise, kmask = jax.random.split(key, 4)
     perm = jax.random.permutation(kperm, vocab_size)
     t0 = jax.random.randint(kinit, (num_clients, batch, 1), 0, vocab_size)
@@ -95,13 +98,13 @@ def make_token_batch(key, num_clients: int, batch: int, seq_len: int,
         nxt = perm[tok]
         flip = jax.random.bernoulli(k, 0.15, tok.shape)
         rnd = jax.random.randint(k, tok.shape, 0, vocab_size)
-        return jnp.where(flip, rnd, nxt)
+        tok = jnp.where(flip, rnd, nxt)
+        return tok, tok
 
     keys = jax.random.split(knoise, seq_len)
-    toks = [t0[..., 0]]
-    for i in range(1, seq_len):
-        toks.append(step(toks[-1], keys[i]))
-    tokens = jnp.stack(toks, axis=-1)                  # (C,B,S)
+    _, rest = jax.lax.scan(step, t0[..., 0], keys[1:])
+    tokens = jnp.concatenate([t0, jnp.moveaxis(rest, 0, -1)],
+                             axis=-1)                  # (C,B,S)
     labels = jnp.concatenate([tokens[..., 1:], tokens[..., :1]], axis=-1)
     out = {"tokens": tokens, "labels": labels}
     return out

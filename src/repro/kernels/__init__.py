@@ -48,9 +48,17 @@ else belongs in plain jnp.
 """
 import jax
 
-# Pallas kernels execute in interpret mode everywhere but real TPUs
-# (this container is CPU-only); shared by ops.py and repro.comm.
+# Pallas kernels execute in interpret mode everywhere but real TPUs,
+# where they lower through Mosaic.
 INTERPRET = jax.default_backend() != "tpu"
+
+
+def interpret_mode(interpret=None) -> bool:
+    """The ``interpret`` flag of a kernel launch: an explicit value
+    wins, None follows the platform (`INTERPRET`).  Every wrapper
+    defaults to None, so a caller that omits the argument never runs
+    the interpreter on a TPU."""
+    return INTERPRET if interpret is None else bool(interpret)
 
 # The kernel registry: one name per fused kernel family, used as the
 # key space of kernels/tuning.json (validated by tools/check_docs.py

@@ -15,7 +15,6 @@ use `repro.core.sophia.apply_update` for apply-only semantics.
 from __future__ import annotations
 
 from repro.comm.flat import flat_spec, pack, unpack
-from repro.kernels import INTERPRET as _INTERPRET
 from repro.kernels.sophia_update import BLOCK_C, sophia_update_flat
 
 
@@ -35,8 +34,6 @@ def sophia_fused_step(params, m, h, grads, h_hat, do_h, *, lr, beta1, beta2,
 
     Returns (new_params, new_m, new_h).
     """
-    if interpret is None:
-        interpret = _INTERPRET
     (t2, m2, h2, g2, hh2), meta = _pack([params, m, h, grads, h_hat])
     t2, m2, h2 = sophia_update_flat(
         t2, m2, h2, g2, hh2, do_h, lr, beta1=beta1, beta2=beta2,

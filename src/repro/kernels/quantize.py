@@ -12,8 +12,9 @@ Layout matches `repro.comm.flat`: (rows, cols) tiles, one quantization
 scale per row.  Stochastic-rounding noise is generated outside the
 kernel with `jax.random` and streamed in, so the reference path
 (`repro.kernels.ref`) sees the identical noise and the Pallas-vs-ref
-equivalence is exact; `interpret=True` runs the kernel body on CPU
-(this container), pass False on a real TPU.
+equivalence is exact.  ``interpret`` defaults to the platform
+(`repro.kernels.interpret_mode`): the interpreter on CPU, Mosaic on a
+TPU.
 
 Dtype contract (`CommConfig.state_dtype` / `moment_dtype` /
 `hessian_dtype`): the state tiles (model / replica / EF streams) may
@@ -45,7 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import tuning
+from repro.kernels import interpret_mode, tuning
 
 BLOCK_R = 256
 BLOCK_C = 1024
@@ -91,7 +92,7 @@ def _quant_kernel(x_ref, u_ref, s_ref, out_ref, *, qmax):
 
 @functools.partial(jax.jit, static_argnames=("qmax", "interpret"))
 def quant_roundtrip_flat(x, noise, scale, *, qmax: int,
-                         interpret: bool = True):
+                         interpret=None):
     """Fused stochastic quantize->dequantize over a (R, C) fp32 buffer.
 
     noise: U[0,1) fp32 array of x.shape; scale: (R, 1) fp32 per-row
@@ -106,7 +107,7 @@ def quant_roundtrip_flat(x, noise, scale, *, qmax: int,
         in_specs=[tile, tile, rowcol],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((R, C), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, noise, scale)
 
 
@@ -131,7 +132,7 @@ def _broadcast_kernel(t_ref, r_ref, e_ref, u_ref, s_ref, m_ref, d_ref,
 
 @functools.partial(jax.jit, static_argnames=("qmax", "interpret"))
 def broadcast_roundtrip_flat(theta, ref, ef, noise, scale, *, qmax: int,
-                             interpret: bool = True):
+                             interpret=None):
     """Fused downlink step over (R, C) fp32 buffers (see
     `repro.comm.downlink.broadcast`).
 
@@ -150,7 +151,7 @@ def broadcast_roundtrip_flat(theta, ref, ef, noise, scale, *, qmax: int,
         out_specs=[tile, tile],
         out_shape=[jax.ShapeDtypeStruct((R, C), theta.dtype),
                    jax.ShapeDtypeStruct((R, C), theta.dtype)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(theta, ref, ef, noise, scale)
 
 
@@ -175,7 +176,7 @@ def _uplink_kernel(t_ref, s_ref, e_ref, u_ref, sc_ref, x_ref, r_ref,
 
 @functools.partial(jax.jit, static_argnames=("qmax", "interpret"))
 def uplink_roundtrip_flat(theta, start, ef, noise, scale, *, qmax: int,
-                          interpret: bool = True):
+                          interpret=None):
     """Fused uplink encode over (R, C) fp32 buffers (see
     `repro.comm.compressors.Compressor.encode_delta`).
 
@@ -195,7 +196,7 @@ def uplink_roundtrip_flat(theta, start, ef, noise, scale, *, qmax: int,
         out_specs=[tile, tile],
         out_shape=[jax.ShapeDtypeStruct((R, C), theta.dtype),
                    jax.ShapeDtypeStruct((R, C), theta.dtype)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(theta, start, ef, noise, scale)
 
 
@@ -207,7 +208,7 @@ def _sign_kernel(x_ref, f_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def sign_roundtrip_flat(x, scale, *, interpret: bool = True):
+def sign_roundtrip_flat(x, scale, *, interpret=None):
     """out = scale * sign(x); scale is a traced scalar."""
     R, C = x.shape
     grid, tile, _, scalar = _grid_specs(R, C, "sign_roundtrip",
@@ -219,7 +220,7 @@ def sign_roundtrip_flat(x, scale, *, interpret: bool = True):
         in_specs=[tile, scalar],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((R, C), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, flags)
 
 
@@ -231,7 +232,7 @@ def _thresh_kernel(x_ref, f_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def topk_threshold_flat(x, thr, *, interpret: bool = True):
+def topk_threshold_flat(x, thr, *, interpret=None):
     """Magnitude sparsifier: keep x where |x| >= thr (the k-th largest
     magnitude, computed outside), zero elsewhere."""
     R, C = x.shape
@@ -244,7 +245,7 @@ def topk_threshold_flat(x, thr, *, interpret: bool = True):
         in_specs=[tile, scalar],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((R, C), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, flags)
 
 
@@ -259,7 +260,7 @@ def topk_threshold_flat(x, thr, *, interpret: bool = True):
 @functools.partial(jax.jit, static_argnames=("qmax", "interpret",
                                              "blocks"))
 def quant_roundtrip_batched(x, noise, scale, *, qmax: int,
-                            interpret: bool = True, blocks=None):
+                            interpret=None, blocks=None):
     """`quant_roundtrip_flat` over an (N, R, C) client stack in one
     launch.  scale: (N, R, 1) per-client per-row scales; blocks: an
     optional static (bn, br, bc) override of the tuned geometry."""
@@ -272,14 +273,14 @@ def quant_roundtrip_batched(x, noise, scale, *, qmax: int,
         in_specs=[tile3, tile3, rowcol3],
         out_specs=tile3,
         out_shape=jax.ShapeDtypeStruct((N, R, C), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, noise, scale)
 
 
 @functools.partial(jax.jit, static_argnames=("qmax", "interpret",
                                              "blocks"))
 def broadcast_roundtrip_batched(theta, ref, ef, noise, scale, *,
-                                qmax: int, interpret: bool = True,
+                                qmax: int, interpret=None,
                                 blocks=None):
     """`broadcast_roundtrip_flat` over (N, R, C) per-client replica /
     EF stacks in one launch.  theta may stay (R, C) — the one server
@@ -296,14 +297,14 @@ def broadcast_roundtrip_batched(theta, ref, ef, noise, scale, *,
         out_specs=[tile3, tile3],
         out_shape=[jax.ShapeDtypeStruct((N, R, C), theta.dtype),
                    jax.ShapeDtypeStruct((N, R, C), theta.dtype)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(theta, ref, ef, noise, scale)
 
 
 @functools.partial(jax.jit, static_argnames=("qmax", "interpret",
                                              "blocks"))
 def uplink_roundtrip_batched(theta, start, ef, noise, scale, *,
-                             qmax: int, interpret: bool = True,
+                             qmax: int, interpret=None,
                              blocks=None):
     """`uplink_roundtrip_flat` over (N, R, C) locally-trained client
     stacks in one launch.  start may stay (R, C) — every client
@@ -320,7 +321,7 @@ def uplink_roundtrip_batched(theta, start, ef, noise, scale, *,
         out_specs=[tile3, tile3],
         out_shape=[jax.ShapeDtypeStruct((N, R, C), theta.dtype),
                    jax.ShapeDtypeStruct((N, R, C), theta.dtype)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(theta, start, ef, noise, scale)
 
 
@@ -332,7 +333,7 @@ def _sign_kernel_batched(x_ref, f_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "blocks"))
-def sign_roundtrip_batched(x, scale, *, interpret: bool = True,
+def sign_roundtrip_batched(x, scale, *, interpret=None,
                            blocks=None):
     """`sign_roundtrip_flat` over an (N, R, C) stack in one launch;
     scale: (N,) per-client scales."""
@@ -346,7 +347,7 @@ def sign_roundtrip_batched(x, scale, *, interpret: bool = True,
         in_specs=[tile3, client3],
         out_specs=tile3,
         out_shape=jax.ShapeDtypeStruct((N, R, C), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, flags)
 
 
@@ -357,7 +358,7 @@ def _thresh_kernel_batched(x_ref, f_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "blocks"))
-def topk_threshold_batched(x, thr, *, interpret: bool = True,
+def topk_threshold_batched(x, thr, *, interpret=None,
                            blocks=None):
     """`topk_threshold_flat` over an (N, R, C) stack in one launch;
     thr: (N,) per-client magnitude thresholds."""
@@ -371,5 +372,5 @@ def topk_threshold_batched(x, thr, *, interpret: bool = True,
         in_specs=[tile3, client3],
         out_specs=tile3,
         out_shape=jax.ShapeDtypeStruct((N, R, C), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, flags)

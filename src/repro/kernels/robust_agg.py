@@ -20,12 +20,12 @@ from HBM exactly once.  ``coordinate_median`` is the same kernel at
 the maximal trim: the surviving one (odd K) or two (even K) middle
 values ARE the median.
 
-Ties break to the lowest arrival index (argmax semantics), matching
-the oracle `repro.kernels.ref.robust_agg_ref` exactly — kernel vs
-ref is pinned per-dtype by tests/test_robust.py.  Layout matches
+Ties break to the lowest arrival index (argmax semantics), as in the
+oracle `repro.kernels.ref.robust_agg_ref`; kernel vs ref is pinned
+per-dtype by tests/test_robust.py to one fp32 rounding per add.  Layout matches
 `repro.comm.flat`: fp32/bf16/fp8 (K, rows, cols) stacks, loads
-upcast to fp32 in VMEM, fp32 out.  ``interpret=True`` runs the body
-on CPU (this container); pass False on a real TPU.
+upcast to fp32 in VMEM, fp32 out.  ``interpret`` defaults to the
+platform (`repro.kernels.interpret_mode`).
 """
 from __future__ import annotations
 
@@ -35,7 +35,28 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import tuning
+from repro.kernels import interpret_mode, tuning
+
+#: elements of one (K, br, bc) wire block: the body keeps about six
+#: fp32 temporaries of the block beside its double-buffered input, so
+#: 2^18 elements (1 MiB at fp32) keeps a launch inside the 16 MiB
+#: scoped-VMEM limit of a TPU v5e at any K the block shrinks for
+BLOCK_ELEMS = 1 << 18
+#: row tile of the narrowest wire dtype (fp8 packs (32, 128) tiles)
+SUBLANES = 32
+LANES = 128
+
+
+def _tile(K, R, C, br, bc):
+    """Shrink the tuned (br, bc) tile so the whole K axis of a block
+    fits `BLOCK_ELEMS`: rows first (whole fp8 row tiles), then lanes
+    (whole 128-lane columns).  VMEM use then stays flat as the
+    arrival count grows, up to K = BLOCK_ELEMS / (32 * 128) = 64."""
+    rows = max(SUBLANES, BLOCK_ELEMS // (K * bc) // SUBLANES * SUBLANES)
+    br = min(br, rows, R)
+    if K * br * bc > BLOCK_ELEMS:
+        bc = max(LANES, BLOCK_ELEMS // (K * br) // LANES * LANES)
+    return br, min(bc, C)
 
 
 def _survivor_mask(x, trim: int):
@@ -72,7 +93,7 @@ def _robust_agg_kernel(x_ref, w_ref, s_ref, out_ref, *, trim,
 @functools.partial(jax.jit, static_argnames=("trim", "normalize",
                                              "interpret", "blocks"))
 def robust_agg_flat(wires, weights, scales, *, trim: int,
-                    normalize: bool = True, interpret: bool = True,
+                    normalize: bool = True, interpret=None,
                     blocks=None):
     """Fused sort-free trimmed-mean/clip combine of K arrival wires.
 
@@ -82,7 +103,8 @@ def robust_agg_flat(wires, weights, scales, *, trim: int,
     unused).  ``trim`` extremes are dropped per coordinate per side
     (static; requires ``2*trim < K``).  Returns the (R, C) fp32
     robust aggregate.  blocks: optional static (br, bc) override of
-    the tuned tile.
+    the tuned tile, which is otherwise shrunk to the K-wide VMEM
+    budget (`_tile`).
     """
     K, R, C = wires.shape
     if not 2 * trim < K:
@@ -91,8 +113,8 @@ def robust_agg_flat(wires, weights, scales, *, trim: int,
         br, bc = blocks
         br, bc = min(br, R), min(bc, C)
     else:
-        br, bc = tuning.blocks_2d("robust_agg", R, C,
-                                  dtype=wires.dtype)
+        br, bc = _tile(K, R, C, *tuning.blocks_2d(
+            "robust_agg", R, C, dtype=wires.dtype))
     # 2D grid — no tile revisits: trimming needs all K wires at once,
     # so K is a block axis, not a grid axis
     grid = (pl.cdiv(R, br), pl.cdiv(C, bc))
@@ -109,5 +131,5 @@ def robust_agg_flat(wires, weights, scales, *, trim: int,
                       pl.BlockSpec((K, 1), lambda i, j: (0, 0))],
             out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((R, C), jnp.float32),
-            interpret=interpret,
+            interpret=interpret_mode(interpret),
         )(wires, w2, s2)

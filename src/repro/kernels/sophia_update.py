@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import tuning
+from repro.kernels import interpret_mode, tuning
 
 BLOCK_R = 256
 BLOCK_C = 1024
@@ -55,15 +55,15 @@ def _sophia_kernel(theta_ref, m_ref, h_ref, g_ref, hhat_ref, flags_ref,
                                              "eps", "weight_decay",
                                              "interpret"))
 def sophia_update_flat(theta, m, h, g, h_hat, do_h, lr, *, beta1, beta2,
-                       rho, eps, weight_decay, interpret: bool = True):
+                       rho, eps, weight_decay, interpret=None):
     """Fused update over a flat (R, C) view. Returns (theta, m, h),
     each in its input's storage dtype (fp32, bf16 or fp8 resident
     state — m and h may each carry their own dtype via
     `CommConfig.moment_dtype` / `hessian_dtype`; compute is fp32
     in-kernel either way).
 
-    interpret=True executes the kernel body in Python on CPU (this
-    container); on a real TPU pass interpret=False.
+    interpret: None follows the platform (the interpreter on CPU,
+    Mosaic on a TPU); pass a bool to force either.
     """
     R, C = theta.shape
     br, bc = tuning.blocks_2d("sophia_update", R, C, dtype=theta.dtype)
@@ -90,7 +90,7 @@ def sophia_update_flat(theta, m, h, g, h_hat, do_h, lr, *, beta1, beta2,
             in_specs=[tile, tile, tile, tile, tile, smem],
             out_specs=[tile, tile, tile],
             out_shape=out_shape,
-            interpret=interpret,
+            interpret=interpret_mode(interpret),
         )(theta, m, h, g, h_hat, flags)
 
 
@@ -99,7 +99,7 @@ def sophia_update_flat(theta, m, h, g, h_hat, do_h, lr, *, beta1, beta2,
                                              "interpret", "blocks"))
 def sophia_update_batched(theta, m, h, g, h_hat, do_h, lr, *, beta1,
                           beta2, rho, eps, weight_decay,
-                          interpret: bool = True, blocks=None):
+                          interpret=None, blocks=None):
     """`sophia_update_flat` over packed (N, R, C) client stacks in ONE
     launch with a leading client grid dimension.  Reuses the same
     elementwise kernel body over 3D blocks, so results are bitwise
@@ -130,5 +130,5 @@ def sophia_update_batched(theta, m, h, g, h_hat, do_h, lr, *, beta1,
             in_specs=[tile3, tile3, tile3, tile3, tile3, smem],
             out_specs=[tile3, tile3, tile3],
             out_shape=out_shape,
-            interpret=interpret,
+            interpret=interpret_mode(interpret),
         )(theta, m, h, g, h_hat, flags)

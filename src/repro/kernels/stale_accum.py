@@ -15,8 +15,7 @@ quantize round-trips in `repro.kernels.quantize`.
 
 Layout matches `repro.comm.flat`: fp32 (rows, cols) tiles.  The
 reference oracle is `repro.kernels.ref.stale_accum_ref`;
-``interpret=True`` runs the kernel body on CPU (this container), pass
-False on a real TPU.
+``interpret`` defaults to the platform (`repro.kernels.interpret_mode`).
 """
 from __future__ import annotations
 
@@ -26,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import tuning
+from repro.kernels import interpret_mode, tuning
 
 BLOCK_R = 256
 BLOCK_C = 1024
@@ -49,7 +48,7 @@ def _stale_accum_kernel(x_ref, w_ref, s_ref, out_ref, *, num_steps,
 
     acc = out_ref[...]
     for kk in range(block_k):
-        acc = acc + w_ref[kk, 0] * x_ref[kk, ...].astype(jnp.float32)
+        acc = acc + w_ref[kk, 0, 0] * x_ref[kk, ...].astype(jnp.float32)
     out_ref[...] = acc
 
     @pl.when(k == num_steps - 1)
@@ -58,7 +57,7 @@ def _stale_accum_kernel(x_ref, w_ref, s_ref, out_ref, *, num_steps,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "blocks"))
-def stale_accum_flat(wires, weights, inv_norm, *, interpret: bool = True,
+def stale_accum_flat(wires, weights, inv_norm, *, interpret=None,
                      blocks=None):
     """Fused weighted accumulate over K arrival wires.
 
@@ -92,7 +91,10 @@ def stale_accum_flat(wires, weights, inv_norm, *, interpret: bool = True,
     # K innermost: each output tile is revisited on consecutive grid
     # steps (the TPU-legal accumulation pattern)
     grid = (pl.cdiv(R, br), pl.cdiv(C, bc), K // bk)
-    w2 = jnp.asarray(weights, jnp.float32).reshape(K, 1)
+    # (K, 1, 1): a (bk, 1, 1) block keeps its last two dims equal to
+    # the array's, which Mosaic's (8, 128) block rule requires — a
+    # (bk, 1) block over a (K, 1) array is refused for K > bk
+    w3 = jnp.asarray(weights, jnp.float32).reshape(K, 1, 1)
     s2 = jnp.asarray(inv_norm, jnp.float32).reshape(1, 1)
     # named scope: annotated span in jax.profiler traces; metadata only
     with jax.named_scope("pallas:stale_accum_flat"):
@@ -102,9 +104,9 @@ def stale_accum_flat(wires, weights, inv_norm, *, interpret: bool = True,
             grid=grid,
             in_specs=[pl.BlockSpec((bk, br, bc),
                                    lambda i, j, k: (k, i, j)),
-                      pl.BlockSpec((bk, 1), lambda i, j, k: (k, 0)),
+                      pl.BlockSpec((bk, 1, 1), lambda i, j, k: (k, 0, 0)),
                       pl.BlockSpec((1, 1), lambda i, j, k: (0, 0))],
             out_specs=pl.BlockSpec((br, bc), lambda i, j, k: (i, j)),
             out_shape=jax.ShapeDtypeStruct((R, C), jnp.float32),
-            interpret=interpret,
-        )(wires, w2, s2)
+            interpret=interpret_mode(interpret),
+        )(wires, w3, s2)

@@ -32,7 +32,7 @@ The file format (versioned, committed at the repo root of the
 package)::
 
     {"version": 1,
-     "backend": "cpu-interpret",
+     "backend": "<what the entries were chosen for>",
      "entries": {"<kernel>": {"block_n": 8, "block_r": 256,
                               "block_c": 1024}, ...}}
 

@@ -1,16 +1,28 @@
 """Training launcher: federated Fed-Sophia (or baselines) on any arch.
 
-On real hardware this runs the full production mesh; on CPU it runs
-reduced configs for end-to-end validation:
+Everything runs on the default device, with no mesh.  On the CPU,
+``--reduced`` shrinks the widths for end-to-end validation:
 
     PYTHONPATH=src python -m repro.launch.train --arch minicpm-2b \
         --reduced --rounds 5
+
+On a TPU, ``--num-layers`` cuts only the depth and keeps every
+published width (``chip_smoke.py`` at the repo root drives this path):
+
+    PYTHONPATH=src python -m repro.launch.train --arch minicpm-2b \
+        --num-layers 2 --clients 1 --batch 1 --seq 2048 \
+        --use-pallas --comm-pallas --compressor int8
+
+`run` builds the engine and state and drives the rounds; `main` and
+``chip_smoke.py`` both call it.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import time
+from typing import Any, Callable, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -30,8 +42,26 @@ from repro.robust import aggregators as robust_agg
 from repro.robust import attacks as robust_attacks
 from repro.sched import VirtualScheduler
 
+#: the checkout's root (src/repro/launch/train.py -> three levels up)
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
-def main():
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already
+    reads it and nothing else is set here; otherwise the cache is the
+    fixed ``.jax_cache/`` at the checkout's root, so a later process in
+    the same checkout finds what an earlier one compiled."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=configs.ARCH_IDS)
     ap.add_argument("--rounds", type=int, default=5)
@@ -44,6 +74,11 @@ def main():
     ap.add_argument("--optimizer", default="fed_sophia")
     ap.add_argument("--reduced", action="store_true",
                     help="reduced model dims (CPU-feasible)")
+    ap.add_argument("--num-layers", type=int, default=0,
+                    help="cut the depth to this many layers, rounded "
+                         "down to whole block_pattern periods (at least "
+                         "one); every width stays as published "
+                         "(0 = the published depth)")
     ap.add_argument("--use-pallas", action="store_true",
                     help="fused Sophia kernel (interpret mode on CPU)")
     # communication layer (repro.comm)
@@ -165,11 +200,40 @@ def main():
                          "(validates the checkpoint's wire-layout "
                          "headers against the current comm config)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def model_config(args):
+    """The architecture's config with the launcher's cuts: ``--reduced``
+    shrinks widths (CPU tests), ``--num-layers`` only the depth."""
     cfg = configs.get_model_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced(d_model=128)
+    if args.num_layers:
+        cfg = cfg.with_depth(args.num_layers)
+    return cfg
+
+
+@dataclasses.dataclass
+class Run:
+    """What `run` leaves behind: the engine, its final state, the
+    compiled round entry point with the inputs it was fed, and one
+    loss and one host wall time per round (sync) or aggregation event
+    (scheduler disciplines; the times are then simulated seconds)."""
+    engine: FedEngine
+    state: Any
+    round_fn: Callable
+    make_batches: Callable[[int], Any]
+    key: Any
+    losses: List[float]
+    seconds: List[float]
+
+
+def run(args: argparse.Namespace) -> Run:
+    """Build the engine and its state from ``args`` and drive
+    ``args.rounds`` rounds (or scheduler events), printing one line
+    each."""
+    cfg = model_config(args)
     over = configs.get_fed_overrides(args.arch)
     ef = {"auto": "auto", "on": True, "off": False}[args.error_feedback]
     comm = CommConfig(compressor=args.compressor,
@@ -322,6 +386,8 @@ def main():
         return batches
 
     spans = obs.SpanLog()
+    losses: List[float] = []
+    seconds: List[float] = []
 
     def round_line(r, loss, lr, dt, row=None):
         clip = (f" clip={row['clip_fraction']:.3f}"
@@ -363,9 +429,10 @@ def main():
                 with spans.span("round"):
                     state, metrics = round_fn(state, make_batches(r),
                                               jax.random.fold_in(key, r))
-                print(round_line(r, float(metrics["loss"]),
-                                 float(metrics["lr"]),
-                                 time.time() - t0), flush=True)
+                losses.append(float(metrics["loss"]))
+                seconds.append(time.time() - t0)
+                print(round_line(r, losses[-1], float(metrics["lr"]),
+                                 seconds[-1]), flush=True)
         elif args.schedule == "sync":
             # obs loop: round metrics (incl. the in-jit Sophia health
             # probes) accumulate in a device-side buffer; the host
@@ -385,6 +452,8 @@ def main():
                         rows = acc.flush()
                     dt = (time.time() - t0) / len(pending)
                     for rr, row in zip(pending, rows):
+                        losses.append(row["loss"])
+                        seconds.append(dt)
                         emit_round(rr, row, dt)
                         print(round_line(rr, row["loss"], row["lr"], dt,
                                          row), flush=True)
@@ -398,6 +467,8 @@ def main():
                                          donate=not args.tree_state)
             state, trace = scheduler.run(state, args.rounds, key)
             for ev in trace.events:
+                losses.append(ev.loss)
+                seconds.append(ev.time)
                 stale = max(ev.staleness) if ev.staleness else 0
                 clip = (f" clip={ev.probes['clip_fraction']:.3f}"
                         if ev.probes else "")
@@ -432,6 +503,14 @@ def main():
             ckpt.save(args.ckpt_dir, state["params"], step=args.rounds,
                       extra=extra)
         print(f"saved checkpoint to {args.ckpt_dir}")
+    return Run(engine=engine, state=state, round_fn=round_fn,
+               make_batches=make_batches, key=key, losses=losses,
+               seconds=seconds)
+
+
+def main(argv: Optional[List[str]] = None) -> None:
+    use_compile_cache()
+    run(parse_args(argv))
 
 
 if __name__ == "__main__":

@@ -80,7 +80,7 @@ def clip_scales(wires, clip_norm) -> jnp.ndarray:
 
 
 def aggregate_stack(robust, wires, weights, *, normalize: bool = True,
-                    use_pallas: bool = False, interpret: bool = True):
+                    use_pallas: bool = False, interpret=None):
     """Robust combine of a (K, rows, cols) stack -> (rows, cols) fp32.
 
     ``weights`` are the caller's per-arrival weights (ones for an
@@ -89,8 +89,9 @@ def aggregate_stack(robust, wires, weights, *, normalize: bool = True,
     survivors; without it (the scheduler's async apply) the surviving
     ``sum_k w_k x_k`` is returned raw — trimmed-away arrivals simply
     never contribute.  ``use_pallas`` routes through the fused
-    sort-free kernel (`repro.kernels.robust_agg`); the jnp path is the
-    conformance oracle `repro.kernels.ref.robust_agg_ref` itself.
+    sort-free kernel (`repro.kernels.robust_agg`), interpreted or not
+    as ``interpret`` says (None follows the platform); the jnp path is
+    the conformance oracle `repro.kernels.ref.robust_agg_ref` itself.
     """
     from repro.kernels import ref as kref
     K = wires.shape[0]

@@ -53,7 +53,6 @@ from repro.comm import downlink as cdown
 from repro.comm import flat as cflat
 from repro.configs.base import SCHED_DISCIPLINES
 from repro.core.schedules import lr_at_round
-from repro.kernels import INTERPRET as _INTERPRET
 from repro.obs.spans import SpanLog
 from repro.robust import aggregators as robust_agg
 from repro.robust import attacks as robust_attacks
@@ -479,11 +478,10 @@ class VirtualScheduler:
             # stale_accum path below untouched — bitwise
             agg_flat = robust_agg.aggregate_stack(
                 self.robust, wires, weights, normalize=normalize,
-                use_pallas=comm.use_pallas, interpret=_INTERPRET)
+                use_pallas=comm.use_pallas)
         elif comm.use_pallas:
             from repro.kernels.stale_accum import stale_accum_flat
-            agg_flat = stale_accum_flat(wires, weights, inv_norm,
-                                        interpret=_INTERPRET)
+            agg_flat = stale_accum_flat(wires, weights, inv_norm)
         else:
             w3 = weights[:, None, None]
             agg_flat = jnp.sum(wires * w3, axis=0)

@@ -150,9 +150,19 @@ def test_sched_degenerate_robust_is_bitwise_mean(setup, sched, robust):
                                    "float8_e4m3fn"])
 @pytest.mark.parametrize("trim", [0, 1, 3])
 @pytest.mark.parametrize("normalize", [True, False])
-def test_robust_agg_kernel_matches_ref_bitwise(dtype, trim, normalize):
-    """Pallas kernel == jnp oracle BITWISE, per storage dtype, trim
-    count and normalization mode (identical op sequence)."""
+def test_robust_agg_kernel_matches_ref(dtype, trim, normalize):
+    """Pallas kernel == jnp oracle to one fp32 rounding per add, per
+    storage dtype, trim count and normalization mode.
+
+    Both run the same op sequence on fp32 values (bf16/fp8 loads upcast
+    exactly), so trimming picks the same survivors; but a compiler may
+    contract ``w * x`` into the running sum as one FMA, which rounds
+    once where the other side rounds twice.  Each of the K adds may
+    then differ by one fp32 ulp of its partial sum, so the difference
+    is bounded by ``K * eps32 * sum_k |w_k s_k x_k|`` per coordinate
+    (over the surviving weight when normalized).  Which cases round
+    alike depends on XLA:CPU's fusion, so no bitwise pin holds; a
+    wrong survivor moves the result by a whole term, far outside."""
     K, R, C = 9, 20, 96
     key = jax.random.PRNGKey(3)
     wires = (10.0 * jax.random.normal(key, (K, R, C))).astype(
@@ -166,7 +176,14 @@ def test_robust_agg_kernel_matches_ref_bitwise(dtype, trim, normalize):
     ref = robust_agg_ref(wires, weights, scales, trim=trim,
                          normalize=normalize)
     assert out.dtype == jnp.float32 and ref.dtype == jnp.float32
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    w, s = np.asarray(weights), np.asarray(scales)
+    terms = np.abs(np.asarray(wires, np.float32) * (w * s)[:, None, None])
+    bound = K * np.finfo(np.float32).eps * terms.sum(axis=0)
+    if normalize:
+        # the surviving weight is at least K - 2*trim smallest weights
+        bound = bound / ((K - 2 * trim) * w.min())
+    diff = np.abs(np.asarray(out) - np.asarray(ref))
+    assert (diff <= bound).all(), float((diff - bound).max())
 
 
 def test_coordinate_median_is_numpy_median():
