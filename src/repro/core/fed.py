@@ -103,6 +103,7 @@ from repro.configs.base import AGGREGATORS, ATTACKS, FedConfig
 from repro.core import sophia
 from repro.core.gnb import gnb_estimate
 from repro.obs import probes as obs_probes
+from repro.obs.spans import phase
 from repro.core.schedules import lr_at_round
 from repro.robust import aggregators as robust_agg
 from repro.robust import attacks as robust_attacks
@@ -519,16 +520,18 @@ class FedEngine:
         dnef_new, h_hat, h_stat)`` with ``None`` for inactive pieces.
         """
         if rt.dn_on:
-            dnm_i, dnef_i = cdown.broadcast(
-                rt.comp_dn, jax.random.fold_in(crng, 0xD0),
-                theta_dn, dnm_i, dnef_i)
-            start = cflat.repack(dnm_i, rt.spec_dn, rt.spec)
+            with phase("wire"):
+                dnm_i, dnef_i = cdown.broadcast(
+                    rt.comp_dn, jax.random.fold_in(crng, 0xD0),
+                    theta_dn, dnm_i, dnef_i)
+                start = cflat.repack(dnm_i, rt.spec_dn, rt.spec)
         else:
             start = theta
         t_i, opt_i, loss = self._local_update_flat(
             rt.spec, start, opt, batch, crng, round_idx, lr)
-        xhat, stat, ef_new = rt.comp.encode_delta(
-            jax.random.fold_in(crng, 0xC0), t_i, start, ef_i)
+        with phase("wire"):
+            xhat, stat, ef_new = rt.comp.encode_delta(
+                jax.random.fold_in(crng, 0xC0), t_i, start, ef_i)
         h_hat = h_stat = None
         if rt.h_on:
             # opt.h is already a wire buffer; only a geometry re-lay
@@ -536,10 +539,11 @@ class FedEngine:
             # between it and the compressor.  The explicit fp32 upcast
             # keeps the wire semantics (scales, payload dtype) fixed
             # when the resident EMAs are stored bf16 (no-op for fp32).
-            h_hat, h_stat = rt.comp_h.roundtrip(
-                jax.random.fold_in(crng, 0x4E),
-                cflat.repack(opt_i.h, rt.spec,
-                             rt.spec_h).astype(jnp.float32))
+            with phase("wire"):
+                h_hat, h_stat = rt.comp_h.roundtrip(
+                    jax.random.fold_in(crng, 0x4E),
+                    cflat.repack(opt_i.h, rt.spec,
+                                 rt.spec_h).astype(jnp.float32))
         return (xhat, stat, ef_new, opt_i, loss,
                 dnm_i if rt.dn_on else None, dnef_i, h_hat, h_stat)
 
@@ -573,27 +577,30 @@ class FedEngine:
                 rt, theta, theta_dn, round_idx, lr, opts, efs, dnms,
                 dnefs, batches, crngs, chunk)
         if rt.dn_on:
-            keys = jax.vmap(
-                lambda k: jax.random.fold_in(k, 0xD0))(crngs)
-            dnms, dnefs = cdown.broadcast_batched(
-                rt.comp_dn, keys, theta_dn, dnms, dnefs)
-            starts = jax.vmap(
-                lambda b: cflat.repack(b, rt.spec_dn, rt.spec))(dnms)
+            with phase("wire"):
+                keys = jax.vmap(
+                    lambda k: jax.random.fold_in(k, 0xD0))(crngs)
+                dnms, dnefs = cdown.broadcast_batched(
+                    rt.comp_dn, keys, theta_dn, dnms, dnefs)
+                starts = jax.vmap(
+                    lambda b: cflat.repack(b, rt.spec_dn, rt.spec))(dnms)
         else:
             starts = theta
         t, opt, losses = self._local_update_flat_batched(
             rt.spec, starts, opts, batches, crngs, round_idx, lr)
-        xhat, stat, ef_new = rt.comp.encode_delta_batched(
-            jax.vmap(lambda k: jax.random.fold_in(k, 0xC0))(crngs),
-            t, starts, efs)
+        with phase("wire"):
+            xhat, stat, ef_new = rt.comp.encode_delta_batched(
+                jax.vmap(lambda k: jax.random.fold_in(k, 0xC0))(crngs),
+                t, starts, efs)
         h_hat = h_stat = None
         if rt.h_on:
-            h_rows = jax.vmap(
-                lambda hrow: cflat.repack(hrow, rt.spec, rt.spec_h)
-            )(opt.h).astype(jnp.float32)
-            h_hat, h_stat = rt.comp_h.roundtrip_batched(
-                jax.vmap(lambda k: jax.random.fold_in(k, 0x4E))(crngs),
-                h_rows)
+            with phase("wire"):
+                h_rows = jax.vmap(
+                    lambda hrow: cflat.repack(hrow, rt.spec, rt.spec_h)
+                )(opt.h).astype(jnp.float32)
+                h_hat, h_stat = rt.comp_h.roundtrip_batched(
+                    jax.vmap(lambda k: jax.random.fold_in(k, 0x4E))(
+                        crngs), h_rows)
         return (xhat, stat, ef_new, opt, losses,
                 dnms if rt.dn_on else None, dnefs, h_hat, h_stat)
 
@@ -649,17 +656,19 @@ class FedEngine:
         round_mode = fed.hessian_every_unit == "round"
         if round_mode:
             do_h_round = (round_idx % fed.tau) == 0
-            h_hat_round = jax.lax.cond(
-                do_h_round,
-                lambda: cflat.pack(gnb_estimate(
-                    task, self._gathered(cflat.unpack(theta, spec)), batch,
-                    jax.random.fold_in(rng, 0x7FFFFFFF),
-                    vg_fn=self._value_and_grad), spec),
-                lambda: cflat.zeros(spec))
+            with phase("gnb"):
+                h_hat_round = jax.lax.cond(
+                    do_h_round,
+                    lambda: cflat.pack(gnb_estimate(
+                        task, self._gathered(cflat.unpack(theta, spec)),
+                        batch, jax.random.fold_in(rng, 0x7FFFFFFF),
+                        vg_fn=self._value_and_grad), spec),
+                    lambda: cflat.zeros(spec))
 
         def step(carry, j):
             t, m_, h_ = carry
-            loss, g, pg = self._flat_value_and_grad(t, batch, spec)
+            with phase("grad"):
+                loss, g, pg = self._flat_value_and_grad(t, batch, spec)
             if round_mode:
                 do_h = do_h_round & (j == 0)   # EMA applied once per refresh
                 hh = h_hat_round
@@ -667,17 +676,19 @@ class FedEngine:
                 tstep = round_idx * fed.local_iters + j
                 do_h = (tstep % fed.tau) == 0
                 rng_j = jax.random.fold_in(rng, j)
-                hh = jax.lax.cond(
-                    do_h,
-                    lambda: cflat.pack(gnb_estimate(
-                        task, pg, batch, rng_j,
-                        vg_fn=self._value_and_grad), spec),
-                    lambda: cflat.zeros(spec))
-            t, m_, h_ = sophia.sophia_step_flat(
-                t, m_, h_, g, hh, do_h,
-                lr=lr, beta1=fed.beta1, beta2=fed.beta2, rho=fed.rho,
-                eps=fed.eps, weight_decay=fed.weight_decay,
-                use_pallas=fed.use_pallas)
+                with phase("gnb"):
+                    hh = jax.lax.cond(
+                        do_h,
+                        lambda: cflat.pack(gnb_estimate(
+                            task, pg, batch, rng_j,
+                            vg_fn=self._value_and_grad), spec),
+                        lambda: cflat.zeros(spec))
+            with phase("sophia"):
+                t, m_, h_ = sophia.sophia_step_flat(
+                    t, m_, h_, g, hh, do_h,
+                    lr=lr, beta1=fed.beta1, beta2=fed.beta2, rho=fed.rho,
+                    eps=fed.eps, weight_decay=fed.weight_decay,
+                    use_pallas=fed.use_pallas)
             return (t, m_, h_), loss
 
         (theta, m, h), losses = jax.lax.scan(
@@ -712,7 +723,8 @@ class FedEngine:
             else:
                 # shared start model: ONE unpacked view feeds every
                 # client's estimator (what vmap hoists anyway)
-                pg0 = self._gathered(cflat.unpack(theta, spec))
+                with phase("gnb"):
+                    pg0 = self._gathered(cflat.unpack(theta, spec))
 
                 def gnb_round():
                     return jax.vmap(
@@ -721,33 +733,38 @@ class FedEngine:
                             jax.random.fold_in(r, 0x7FFFFFFF),
                             vg_fn=self._value_and_grad), spec)
                     )(batches, rngs)
-            h_hat_round = jax.lax.cond(
-                do_h_round, gnb_round, lambda: cflat.zeros(spec, (N,)))
+            with phase("gnb"):
+                h_hat_round = jax.lax.cond(
+                    do_h_round, gnb_round,
+                    lambda: cflat.zeros(spec, (N,)))
 
         def step(carry, j):
             t, m_, h_ = carry
-            losses, g, pgs = jax.vmap(
-                lambda tt, bb: self._flat_value_and_grad(tt, bb, spec)
-            )(t, batches)
+            with phase("grad"):
+                losses, g, pgs = jax.vmap(
+                    lambda tt, bb: self._flat_value_and_grad(tt, bb, spec)
+                )(t, batches)
             if round_mode:
                 do_h = do_h_round & (j == 0)
                 hh = h_hat_round
             else:
                 tstep = round_idx * fed.local_iters + j
                 do_h = (tstep % fed.tau) == 0
-                hh = jax.lax.cond(
-                    do_h,
-                    lambda: jax.vmap(
-                        lambda pg, bb, r: cflat.pack(gnb_estimate(
-                            task, pg, bb, jax.random.fold_in(r, j),
-                            vg_fn=self._value_and_grad), spec)
-                    )(pgs, batches, rngs),
-                    lambda: cflat.zeros(spec, (N,)))
-            t, m_, h_ = sophia.sophia_step_flat(
-                t, m_, h_, g, hh, do_h,
-                lr=lr, beta1=fed.beta1, beta2=fed.beta2, rho=fed.rho,
-                eps=fed.eps, weight_decay=fed.weight_decay,
-                use_pallas=fed.use_pallas)
+                with phase("gnb"):
+                    hh = jax.lax.cond(
+                        do_h,
+                        lambda: jax.vmap(
+                            lambda pg, bb, r: cflat.pack(gnb_estimate(
+                                task, pg, bb, jax.random.fold_in(r, j),
+                                vg_fn=self._value_and_grad), spec)
+                        )(pgs, batches, rngs),
+                        lambda: cflat.zeros(spec, (N,)))
+            with phase("sophia"):
+                t, m_, h_ = sophia.sophia_step_flat(
+                    t, m_, h_, g, hh, do_h,
+                    lr=lr, beta1=fed.beta1, beta2=fed.beta2, rho=fed.rho,
+                    eps=fed.eps, weight_decay=fed.weight_decay,
+                    use_pallas=fed.use_pallas)
             return (t, m_, h_), losses
 
         t0 = (theta if theta.ndim == 3
@@ -759,7 +776,8 @@ class FedEngine:
     def _local_sgd_flat(self, spec, theta, batch, rng, lr):
         """Flat-resident local SGD: the update is one flat axpy."""
         def step(t, j):
-            loss, g, _ = self._flat_value_and_grad(t, batch, spec)
+            with phase("grad"):
+                loss, g, _ = self._flat_value_and_grad(t, batch, spec)
             return t - lr * g, loss
 
         theta, losses = jax.lax.scan(step, theta,
@@ -772,9 +790,10 @@ class FedEngine:
         N = rngs.shape[0]
 
         def step(t, j):
-            losses, g, _ = jax.vmap(
-                lambda tt, bb: self._flat_value_and_grad(tt, bb, spec)
-            )(t, batches)
+            with phase("grad"):
+                losses, g, _ = jax.vmap(
+                    lambda tt, bb: self._flat_value_and_grad(tt, bb, spec)
+                )(t, batches)
             return t - lr * g, losses
 
         t0 = (theta if theta.ndim == 3
@@ -1017,7 +1036,8 @@ class FedEngine:
             new_t, new_opt, losses = self._local_update_flat_batched(
                 spec, theta, opts, batches, client_rngs, round_idx, lr)
             if not adversarial:
-                agg_flat = jnp.mean(new_t, axis=0)
+                with phase("combine"):
+                    agg_flat = jnp.mean(new_t, axis=0)
         elif adversarial:
             # robust/attacked sequential: the scan stacks each
             # client's params (same memory as the parallel stack —
@@ -1034,33 +1054,37 @@ class FedEngine:
                 opt, batch, crng = xs
                 t_i, opt_i, loss = self._local_update_flat(
                     spec, theta, opt, batch, crng, round_idx, lr)
-                return acc + t_i / C, (opt_i, loss)
+                with phase("combine"):
+                    acc = acc + t_i / C
+                return acc, (opt_i, loss)
             agg_flat, (new_opt, losses) = jax.lax.scan(
                 scan_body, jnp.zeros_like(theta),
                 (opts, batches, client_rngs))
 
-        if adversarial:
-            # the direct path carries whole client models; attacks and
-            # robust combination are defined on the *contribution
-            # delta* vs the round-start model — equivalent to the wire
-            # transforms of the comm path on an uncompressed uplink
-            deltas = new_t - theta
-            if attack_on:
-                deltas = robust_attacks.attack_wires(
-                    rb, deltas,
-                    jnp.asarray(robust_attacks.byzantine_mask(rb, C)),
-                    client_rngs[0])
-            agg_flat = theta + robust_agg.aggregate_stack(
-                rb, deltas, jnp.ones((C,), jnp.float32),
-                normalize=True, use_pallas=fed.comm.use_pallas)
-
-        if packed:
-            state = self._apply_aggregate_flat(state, agg_flat)
-        else:
-            state = self._apply_aggregate(state,
-                                          cflat.unpack(agg_flat, spec))
+        with phase("combine"):
+            if adversarial:
+                # the direct path carries whole client models; attacks
+                # and robust combination are defined on the
+                # *contribution delta* vs the round-start model —
+                # equivalent to the wire transforms of the comm path on
+                # an uncompressed uplink
+                deltas = new_t - theta
+                if attack_on:
+                    deltas = robust_attacks.attack_wires(
+                        rb, deltas,
+                        jnp.asarray(robust_attacks.byzantine_mask(rb, C)),
+                        client_rngs[0])
+                agg_flat = theta + robust_agg.aggregate_stack(
+                    rb, deltas, jnp.ones((C,), jnp.float32),
+                    normalize=True, use_pallas=fed.comm.use_pallas)
+            if packed:
+                state = self._apply_aggregate_flat(state, agg_flat)
+            else:
+                state = self._apply_aggregate(state,
+                                              cflat.unpack(agg_flat, spec))
         if stateful:
-            state = {**state, "client_opt": self._store_opt(new_opt)}
+            with phase("rows"):
+                state = {**state, "client_opt": self._store_opt(new_opt)}
         return state, jnp.mean(losses)
 
     def _round_comm(self, state, batches, client_rngs, round_idx, lr, rng,
@@ -1095,7 +1119,11 @@ class FedEngine:
         packed = self.params_packed(params)
         theta = (params.astype(jnp.float32) if packed
                  else cflat.pack(params, spec))
-        theta_dn = cflat.repack(theta, spec, rt.spec_dn) if dn_on else None
+        if dn_on:
+            with phase("wire"):
+                theta_dn = cflat.repack(theta, spec, rt.spec_dn)
+        else:
+            theta_dn = None
         idx = participation_indices(
             jax.random.fold_in(rng, PARTICIPATION_SALT + comm.seed), C, S)
         stateful = self._stateful()
@@ -1110,9 +1138,10 @@ class FedEngine:
             return (None if tree is None
                     else jax.tree.map(lambda x: x[idx], tree))
 
-        opts_g, ef_g = take(opts), take(ef)
-        dnm_g, dnef_g = take(dn_model), take(dn_ef)
-        batches_g, rngs_g = take(batches), client_rngs[idx]
+        with phase("rows"):
+            opts_g, ef_g = take(opts), take(ef)
+            dnm_g, dnef_g = take(dn_model), take(dn_ef)
+            batches_g, rngs_g = take(batches), client_rngs[idx]
 
         client = functools.partial(self.comm_client_step, rt, theta,
                                    theta_dn, round_idx, lr)
@@ -1128,15 +1157,16 @@ class FedEngine:
         robust_on = robust_agg.resolve(rb, S) != "mean"
 
         def combine_wires(wires):
-            if attack_on:
-                byz = jnp.asarray(robust_attacks.byzantine_mask(rb, C))
-                wires = robust_attacks.attack_wires(rb, wires, byz[idx],
-                                                    rng)
-            if robust_on:
-                return robust_agg.aggregate_stack(
-                    rb, wires, jnp.ones((S,), jnp.float32),
-                    normalize=True, use_pallas=comm.use_pallas)
-            return jnp.sum(wires, axis=0) / S
+            with phase("combine"):
+                if attack_on:
+                    byz = jnp.asarray(robust_attacks.byzantine_mask(rb, C))
+                    wires = robust_attacks.attack_wires(
+                        rb, wires, byz[idx], rng)
+                if robust_on:
+                    return robust_agg.aggregate_stack(
+                        rb, wires, jnp.ones((S,), jnp.float32),
+                        normalize=True, use_pallas=comm.use_pallas)
+                return jnp.sum(wires, axis=0) / S
 
         if fed.strategy == "parallel":
             (wires, stats, ef_new_g, opt_new_g, losses, dnm_new_g,
@@ -1144,12 +1174,13 @@ class FedEngine:
                 rt, theta, theta_dn, round_idx, lr,
                 opts_g, ef_g, dnm_g, dnef_g, batches_g, rngs_g)
             agg_flat = combine_wires(wires)
-            wstat = jnp.sum(stats) / S
-            if dn_on:
-                dn_mean = jnp.sum(dnm_new_g, axis=0) / S
-            if h_on:
-                h_agg = jnp.sum(h_hat_g, axis=0) / S
-                h_wstat = jnp.sum(h_stat_g) / S
+            with phase("combine"):
+                wstat = jnp.sum(stats) / S
+                if dn_on:
+                    dn_mean = jnp.sum(dnm_new_g, axis=0) / S
+                if h_on:
+                    h_agg = jnp.sum(h_hat_g, axis=0) / S
+                    h_wstat = jnp.sum(h_stat_g) / S
         else:
             collect = attack_on or robust_on
 
@@ -1160,14 +1191,15 @@ class FedEngine:
                                          batch, crng)
                 # robust/attacked runs stack the wires (trimming needs
                 # the whole cohort) instead of accumulating the mean
-                if not collect:
-                    acc = {**acc, "w": acc["w"] + wire / S}
-                acc = {**acc, "s": acc["s"] + stat / S}
-                if dn_on:
-                    acc = {**acc, "dn": acc["dn"] + dnm_new / S}
-                if h_on:
-                    acc = {**acc, "h": acc["h"] + h_hat / S,
-                           "hs": acc["hs"] + h_stat / S}
+                with phase("combine"):
+                    if not collect:
+                        acc = {**acc, "w": acc["w"] + wire / S}
+                    acc = {**acc, "s": acc["s"] + stat / S}
+                    if dn_on:
+                        acc = {**acc, "dn": acc["dn"] + dnm_new / S}
+                    if h_on:
+                        acc = {**acc, "h": acc["h"] + h_hat / S,
+                               "hs": acc["hs"] + h_stat / S}
                 ys = (ef_i_new, opt_i, loss, dnm_new, dnef_new)
                 return acc, (ys + (wire,)) if collect else ys
             acc0 = {"s": jnp.zeros((), jnp.float32)}
@@ -1189,49 +1221,55 @@ class FedEngine:
             if h_on:
                 h_agg, h_wstat = acc["h"], acc["hs"]
 
-        agg_flat = comp.server_combine(agg_flat, wstat)
-        if dn_on:
-            # clients trained from their OWN received replicas: the
-            # aggregated model is mean_S(replica + decoded uplink delta),
-            # expressed as a server-side delta vs the true model
-            corr = cflat.repack(dn_mean - theta_dn, rt.spec_dn, spec)
-            agg_flat = agg_flat + corr
-        # the server model update is a flat axpy; the pytree appears
-        # only at the state boundary (and not at all in packed-
-        # resident mode)
-        if packed:
-            state = self._apply_aggregate_flat(state, theta + agg_flat)
-        else:
-            state = self._apply_aggregate(
-                state, cflat.unpack(theta + agg_flat, spec))
+        with phase("combine"):
+            agg_flat = comp.server_combine(agg_flat, wstat)
+            if dn_on:
+                # clients trained from their OWN received replicas: the
+                # aggregated model is mean_S(replica + decoded uplink
+                # delta), expressed as a server-side delta vs the true
+                # model
+                corr = cflat.repack(dn_mean - theta_dn, rt.spec_dn, spec)
+                agg_flat = agg_flat + corr
+            # the server model update is a flat axpy; the pytree
+            # appears only at the state boundary (and not at all in
+            # packed-resident mode)
+            if packed:
+                state = self._apply_aggregate_flat(state, theta + agg_flat)
+            else:
+                state = self._apply_aggregate(
+                    state, cflat.unpack(theta + agg_flat, spec))
         if stateful:
             # scatter the participants' optimizer state rows back
             # (downcast to the per-buffer resident dtypes; no-op for
             # fp32)
-            new_opts = jax.tree.map(
-                lambda full, g: full.at[idx].set(g),
-                state["client_opt"], self._store_opt(opt_new_g))
+            with phase("rows"):
+                new_opts = jax.tree.map(
+                    lambda full, g: full.at[idx].set(g),
+                    state["client_opt"], self._store_opt(opt_new_g))
             if h_on:
                 # curvature averaging: every participant's h re-synced
                 # to the (re-quantized) common averaged broadcast
-                h_down, _ = rt.comp_h.roundtrip(
-                    jax.random.fold_in(rng, 0x4D),
-                    rt.comp_h.server_combine(h_agg, h_wstat))
-                h_common = cflat.repack(h_down, rt.spec_h, spec).astype(
-                    new_opts.h.dtype)
-                new_opts = new_opts._replace(h=new_opts.h.at[idx].set(
-                    jnp.broadcast_to(h_common[None],
-                                     (S,) + h_common.shape)))
+                with phase("wire"):
+                    h_down, _ = rt.comp_h.roundtrip(
+                        jax.random.fold_in(rng, 0x4D),
+                        rt.comp_h.server_combine(h_agg, h_wstat))
+                    h_common = cflat.repack(
+                        h_down, rt.spec_h, spec).astype(new_opts.h.dtype)
+                with phase("rows"):
+                    new_opts = new_opts._replace(h=new_opts.h.at[idx].set(
+                        jnp.broadcast_to(h_common[None],
+                                         (S,) + h_common.shape)))
             state = {**state, "client_opt": new_opts}
-        if ef is not None:
-            state = {**state, "comm_ef":
-                     ef.at[idx].set(self._store(ef_new_g))}
-        if dn_model is not None:
-            state = {**state, cdown.MODEL_KEY:
-                     dn_model.at[idx].set(self._store(dnm_new_g))}
-        if dn_ef is not None:
-            state = {**state, cdown.EF_KEY:
-                     dn_ef.at[idx].set(self._store(dnef_new_g))}
+        with phase("rows"):
+            if ef is not None:
+                state = {**state, "comm_ef":
+                         ef.at[idx].set(self._store(ef_new_g))}
+            if dn_model is not None:
+                state = {**state, cdown.MODEL_KEY:
+                         dn_model.at[idx].set(self._store(dnm_new_g))}
+            if dn_ef is not None:
+                state = {**state, cdown.EF_KEY:
+                         dn_ef.at[idx].set(self._store(dnef_new_g))}
         return state, jnp.mean(losses)
 
     # ------------------------------------------------ server-side optimizers

@@ -267,14 +267,15 @@ def quant_roundtrip_batched(x, noise, scale, *, qmax: int,
     N, R, C = x.shape
     grid, tile3, rowcol3, _, _ = _grid_specs3(
         N, R, C, "quant_roundtrip", blocks, dtype=x.dtype)
-    return pl.pallas_call(
-        functools.partial(_quant_kernel, qmax=qmax),
-        grid=grid,
-        in_specs=[tile3, tile3, rowcol3],
-        out_specs=tile3,
-        out_shape=jax.ShapeDtypeStruct((N, R, C), x.dtype),
-        interpret=interpret_mode(interpret),
-    )(x, noise, scale)
+    with jax.named_scope("pallas:quant_roundtrip_batched"):
+        return pl.pallas_call(
+            functools.partial(_quant_kernel, qmax=qmax),
+            grid=grid,
+            in_specs=[tile3, tile3, rowcol3],
+            out_specs=tile3,
+            out_shape=jax.ShapeDtypeStruct((N, R, C), x.dtype),
+            interpret=interpret_mode(interpret),
+        )(x, noise, scale)
 
 
 @functools.partial(jax.jit, static_argnames=("qmax", "interpret",
@@ -290,15 +291,16 @@ def broadcast_roundtrip_batched(theta, ref, ef, noise, scale, *,
     grid, tile3, rowcol3, _, shared2 = _grid_specs3(
         N, R, C, "broadcast_roundtrip", blocks, dtype=theta.dtype)
     t_spec = shared2 if theta.ndim == 2 else tile3
-    return pl.pallas_call(
-        functools.partial(_broadcast_kernel, qmax=qmax),
-        grid=grid,
-        in_specs=[t_spec, tile3, tile3, tile3, rowcol3],
-        out_specs=[tile3, tile3],
-        out_shape=[jax.ShapeDtypeStruct((N, R, C), theta.dtype),
-                   jax.ShapeDtypeStruct((N, R, C), theta.dtype)],
-        interpret=interpret_mode(interpret),
-    )(theta, ref, ef, noise, scale)
+    with jax.named_scope("pallas:broadcast_roundtrip_batched"):
+        return pl.pallas_call(
+            functools.partial(_broadcast_kernel, qmax=qmax),
+            grid=grid,
+            in_specs=[t_spec, tile3, tile3, tile3, rowcol3],
+            out_specs=[tile3, tile3],
+            out_shape=[jax.ShapeDtypeStruct((N, R, C), theta.dtype),
+                       jax.ShapeDtypeStruct((N, R, C), theta.dtype)],
+            interpret=interpret_mode(interpret),
+        )(theta, ref, ef, noise, scale)
 
 
 @functools.partial(jax.jit, static_argnames=("qmax", "interpret",
@@ -314,15 +316,16 @@ def uplink_roundtrip_batched(theta, start, ef, noise, scale, *,
     grid, tile3, rowcol3, _, shared2 = _grid_specs3(
         N, R, C, "uplink_roundtrip", blocks, dtype=theta.dtype)
     s_spec = shared2 if start.ndim == 2 else tile3
-    return pl.pallas_call(
-        functools.partial(_uplink_kernel, qmax=qmax),
-        grid=grid,
-        in_specs=[tile3, s_spec, tile3, tile3, rowcol3],
-        out_specs=[tile3, tile3],
-        out_shape=[jax.ShapeDtypeStruct((N, R, C), theta.dtype),
-                   jax.ShapeDtypeStruct((N, R, C), theta.dtype)],
-        interpret=interpret_mode(interpret),
-    )(theta, start, ef, noise, scale)
+    with jax.named_scope("pallas:uplink_roundtrip_batched"):
+        return pl.pallas_call(
+            functools.partial(_uplink_kernel, qmax=qmax),
+            grid=grid,
+            in_specs=[tile3, s_spec, tile3, tile3, rowcol3],
+            out_specs=[tile3, tile3],
+            out_shape=[jax.ShapeDtypeStruct((N, R, C), theta.dtype),
+                       jax.ShapeDtypeStruct((N, R, C), theta.dtype)],
+            interpret=interpret_mode(interpret),
+        )(theta, start, ef, noise, scale)
 
 
 def _sign_kernel_batched(x_ref, f_ref, out_ref):

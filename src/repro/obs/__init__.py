@@ -14,8 +14,9 @@ The subsystem has four layers, each usable on its own:
   (`repro.obs.buffer`), the packed device-side metrics buffer that
   defers the host sync to the eval/checkpoint flush boundary.
 * `repro.obs.spans`   — host-side span timers correlated with the
-  scheduler's virtual clock, and the opt-in `jax.profiler` trace
-  hooks (`--profile-dir` in `repro.launch.train` / `serve`).
+  scheduler's virtual clock, the opt-in `jax.profiler` trace hooks
+  (`--profile-dir` in `repro.launch.train` / `serve`), and the round's
+  named device phases (`phase`, `PHASES`).
 * `repro.obs.trace`   — Chrome Trace Event / Perfetto export of a
   run's trace contexts (`ObsConfig.trace`), plus the structural
   validator `make obs-trace-smoke` gates on.
@@ -30,7 +31,7 @@ from repro.obs.schema import (SCHEMA_VERSION, SUPPORTED_SCHEMA_VERSIONS,
                               ObsSchemaError, describe, fingerprint,
                               validate_record)
 from repro.obs.sinks import JsonlSink, RingSink, RunRecorder
-from repro.obs.spans import SpanLog, annotate, profile_trace
+from repro.obs.spans import PHASES, SpanLog, annotate, phase, profile_trace
 from repro.obs.trace import chrome_trace, validate_chrome_trace
 
 __all__ = [
@@ -38,7 +39,7 @@ __all__ = [
     "describe", "fingerprint", "validate_record",
     "JsonlSink", "RingSink", "RunRecorder",
     "MetricsAccumulator", "PROBE_METRICS", "sophia_health",
-    "SpanLog", "annotate", "profile_trace",
+    "SpanLog", "annotate", "profile_trace", "PHASES", "phase",
     "ObsLogError", "read_records",
     "chrome_trace", "validate_chrome_trace",
 ]

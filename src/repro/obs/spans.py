@@ -12,6 +12,12 @@ point, two views.
 `profile_trace` is the opt-in trace context: a no-op unless a
 directory is given, and degrades to a warning (never a crash) when the
 installed jax cannot start a trace on this backend.
+
+`phase` names one phase of the federated round on the device: a
+``jax.named_scope("fed.<phase>")`` that the compiled round carries in
+each instruction's ``op_name`` metadata, so a device trace groups the
+round's time by phase (`PHASES`).  Metadata only: the compiled
+program is the same with or without it.
 """
 from __future__ import annotations
 
@@ -20,6 +26,21 @@ from contextlib import contextmanager
 from typing import List, Optional
 
 import jax
+
+
+#: the round's device phases, each the scope ``fed.<name>`` (`phase`):
+#: local forward/backward, the GNB curvature estimate, the Sophia
+#: update, the comm streams' encode/decode, the server's combine, and
+#: the gather/scatter of client rows in the resident stacks
+PHASES = ("grad", "gnb", "sophia", "wire", "combine", "rows")
+
+
+def phase(name: str):
+    """The named device scope of one phase of the round (context
+    manager); one ``fed.`` phase never encloses another."""
+    if name not in PHASES:
+        raise ValueError(f"unknown phase {name!r} (want one of {PHASES})")
+    return jax.named_scope("fed." + name)
 
 
 def annotate(name: str):

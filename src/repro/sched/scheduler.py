@@ -53,7 +53,7 @@ from repro.comm import downlink as cdown
 from repro.comm import flat as cflat
 from repro.configs.base import SCHED_DISCIPLINES
 from repro.core.schedules import lr_at_round
-from repro.obs.spans import SpanLog
+from repro.obs.spans import SpanLog, phase
 from repro.robust import aggregators as robust_agg
 from repro.robust import attacks as robust_attacks
 from repro.sched import latency
@@ -427,19 +427,21 @@ class VirtualScheduler:
         theta = (params.astype(jnp.float32)
                  if engine.params_packed(params)
                  else cflat.pack(params, rt.spec))
-        theta_dn = (cflat.repack(theta, rt.spec, rt.spec_dn)
-                    if rt.dn_on else None)
+        with phase("wire"):
+            theta_dn = (cflat.repack(theta, rt.spec, rt.spec_dn)
+                        if rt.dn_on else None)
 
         def take(tree):
             return (None if tree is None
                     else jax.tree.map(lambda x: x[idx], tree))
 
-        opts_g = take(state.get("client_opt") if self._stateful
-                      else None)
-        ef_g = take(state.get("comm_ef"))
-        dnm_g = take(state.get(cdown.MODEL_KEY))
-        dnef_g = take(state.get(cdown.EF_KEY))
-        batches_g = take(batches)
+        with phase("rows"):
+            opts_g = take(state.get("client_opt") if self._stateful
+                          else None)
+            ef_g = take(state.get("comm_ef"))
+            dnm_g = take(state.get(cdown.MODEL_KEY))
+            dnef_g = take(state.get(cdown.EF_KEY))
+            batches_g = take(batches)
         rngs_g = jax.vmap(lambda i: jax.random.fold_in(rng_v, i))(idx)
 
         out = engine.comm_client_step_batched(
@@ -469,65 +471,69 @@ class VirtualScheduler:
         rt = engine.runtime_for(params)
         packed = engine.params_packed(params)
         normalize = self.sched.discipline == "semisync"
-        wsum = jnp.sum(weights)
-        inv_norm = (1.0 / wsum) if normalize else jnp.float32(1.0)
-        if robust_agg.resolve(self.robust, wires.shape[0]) != "mean":
-            # robust combine of the arrival stack (same staleness
-            # weights and normalization semantics); degenerate
-            # parameterizations resolve to "mean" above and keep the
-            # stale_accum path below untouched — bitwise
-            agg_flat = robust_agg.aggregate_stack(
-                self.robust, wires, weights, normalize=normalize,
-                use_pallas=comm.use_pallas)
-        elif comm.use_pallas:
-            from repro.kernels.stale_accum import stale_accum_flat
-            agg_flat = stale_accum_flat(wires, weights, inv_norm)
-        else:
-            w3 = weights[:, None, None]
-            agg_flat = jnp.sum(wires * w3, axis=0)
-            agg_flat = agg_flat / wsum if normalize else agg_flat
-        wstat = jnp.sum(stats * weights)
-        if normalize:
-            wstat = wstat / wsum
-        agg_flat = rt.comp.server_combine(agg_flat, wstat)
-        theta = (params.astype(jnp.float32) if packed
-                 else cflat.pack(params, rt.spec))
-        if rt.dn_on:
-            # arrivals trained from their OWN received replicas: fold
-            # in each arrival's (replica - current model) reference
-            # shift, weighted like its delta
-            packed_now = cflat.repack(theta, rt.spec, rt.spec_dn)
-            dn_acc = jnp.sum(dnm_rows * weights[:, None, None], axis=0)
-            if normalize:
-                corr = dn_acc / wsum - packed_now
+        with phase("combine"):
+            wsum = jnp.sum(weights)
+            inv_norm = (1.0 / wsum) if normalize else jnp.float32(1.0)
+            if robust_agg.resolve(self.robust, wires.shape[0]) != "mean":
+                # robust combine of the arrival stack (same staleness
+                # weights and normalization semantics); degenerate
+                # parameterizations resolve to "mean" above and keep
+                # the stale_accum path below untouched — bitwise
+                agg_flat = robust_agg.aggregate_stack(
+                    self.robust, wires, weights, normalize=normalize,
+                    use_pallas=comm.use_pallas)
+            elif comm.use_pallas:
+                from repro.kernels.stale_accum import stale_accum_flat
+                agg_flat = stale_accum_flat(wires, weights, inv_norm)
             else:
-                corr = dn_acc - wsum * packed_now
-            agg_flat = agg_flat + cflat.repack(corr, rt.spec_dn, rt.spec)
-        # flat axpy + ONE unpack at the state boundary (no per-leaf
-        # delta application; none at all in packed-resident mode)
-        if packed:
-            state = engine._apply_aggregate_flat(state, theta + agg_flat)
-        else:
-            state = engine._apply_aggregate(
-                state, cflat.unpack(theta + agg_flat, rt.spec))
+                w3 = weights[:, None, None]
+                agg_flat = jnp.sum(wires * w3, axis=0)
+                agg_flat = agg_flat / wsum if normalize else agg_flat
+            wstat = jnp.sum(stats * weights)
+            if normalize:
+                wstat = wstat / wsum
+            agg_flat = rt.comp.server_combine(agg_flat, wstat)
+            theta = (params.astype(jnp.float32) if packed
+                     else cflat.pack(params, rt.spec))
+            if rt.dn_on:
+                # arrivals trained from their OWN received replicas:
+                # fold in each arrival's (replica - current model)
+                # reference shift, weighted like its delta
+                packed_now = cflat.repack(theta, rt.spec, rt.spec_dn)
+                dn_acc = jnp.sum(dnm_rows * weights[:, None, None], axis=0)
+                if normalize:
+                    corr = dn_acc / wsum - packed_now
+                else:
+                    corr = dn_acc - wsum * packed_now
+                agg_flat = agg_flat + cflat.repack(corr, rt.spec_dn,
+                                                   rt.spec)
+            # flat axpy + ONE unpack at the state boundary (no per-leaf
+            # delta application; none at all in packed-resident mode)
+            if packed:
+                state = engine._apply_aggregate_flat(state,
+                                                     theta + agg_flat)
+            else:
+                state = engine._apply_aggregate(
+                    state, cflat.unpack(theta + agg_flat, rt.spec))
         state = {**state, "round": state["round"] + 1}
         # scatters downcast the arrivals' rows back to the resident
         # storage dtype (no-op for fp32)
-        if self._stateful and opt_rows is not None:
-            state = {**state, "client_opt": jax.tree.map(
-                lambda full, g: full.at[idx].set(g),
-                state["client_opt"], engine._store_opt(opt_rows))}
-        if ef_rows is not None:
-            state = {**state, "comm_ef": state["comm_ef"].at[idx].set(
-                engine._store(ef_rows))}
-        if dnm_rows is not None:
-            state = {**state, cdown.MODEL_KEY:
-                     state[cdown.MODEL_KEY].at[idx].set(
-                         engine._store(dnm_rows))}
-        if dnef_rows is not None:
-            state = {**state, cdown.EF_KEY:
-                     state[cdown.EF_KEY].at[idx].set(
-                         engine._store(dnef_rows))}
+        with phase("rows"):
+            if self._stateful and opt_rows is not None:
+                state = {**state, "client_opt": jax.tree.map(
+                    lambda full, g: full.at[idx].set(g),
+                    state["client_opt"], engine._store_opt(opt_rows))}
+            if ef_rows is not None:
+                state = {**state, "comm_ef": state["comm_ef"].at[idx].set(
+                    engine._store(ef_rows))}
+            if dnm_rows is not None:
+                state = {**state, cdown.MODEL_KEY:
+                         state[cdown.MODEL_KEY].at[idx].set(
+                             engine._store(dnm_rows))}
+            if dnef_rows is not None:
+                state = {**state, cdown.EF_KEY:
+                         state[cdown.EF_KEY].at[idx].set(
+                             engine._store(dnef_rows))}
         return state
 
     # ------------------------------------------------------------- helpers

@@ -15,6 +15,7 @@ import dataclasses
 import json
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,8 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.configs.base import FedConfig, ObsConfig, SchedConfig
+from repro.configs.base import (CommConfig, FedConfig, ObsConfig,
+                               SchedConfig)
 from repro.core.fed import FedEngine
 from repro.data import synthetic as syn
 from repro.metrics import energy
@@ -362,6 +364,77 @@ def test_span_log_records():
     for r in recs:
         obs.validate_record(r)
         assert r["wall_s"] >= 0.0
+
+
+# ------------------------------------------------- device phases
+def _phases_in(hlo_text):
+    """The ``fed.*`` phases named in a compiled module's op_name
+    metadata; no op_name names two different phases."""
+    found = set()
+    for op in re.findall(r'op_name="([^"]*)"', hlo_text):
+        names = set(re.findall(r"fed\.(\w+)", op))
+        assert len(names) <= 1, op
+        found |= names
+    return found
+
+
+ALL_PHASES = set(obs.PHASES)
+WIRES = CommConfig(compressor="int8", downlink_compressor="int8",
+                   hessian_compressor="int8", participation=0.5)
+
+
+@pytest.mark.parametrize("path,strategy,unit,comm,phases", [
+    ("comm", "parallel", "step", WIRES, ALL_PHASES),
+    ("comm", "parallel", "round", WIRES, ALL_PHASES),
+    ("comm", "sequential", "step", WIRES, ALL_PHASES),
+    ("comm", "sequential", "round", WIRES, ALL_PHASES),
+    # fp32 state: the direct path's store of the client rows is no op
+    ("direct", "parallel", "step", CommConfig(),
+     ALL_PHASES - {"wire", "rows"}),
+])
+def test_round_carries_its_phase_scopes(setup, path, strategy, unit, comm,
+                                        phases):
+    """Every phase a round's path runs is a named device scope of its
+    compiled program (the chunked dispatch's among them, the GNB
+    estimate hoisted per round or per step), and no phase nests in
+    another."""
+    task, batch_fn = setup
+    fed = _fed(strategy=strategy, comm=comm, hessian_every_unit=unit,
+               sched=SchedConfig(dispatch_chunk=2))
+    eng = FedEngine(task, fed)
+    assert eng.uses_direct_path() == (path == "direct")
+    state = eng.pack_state(eng.init(jax.random.PRNGKey(2)))
+    text = eng.round_fn(donate=False).lower(
+        state, batch_fn(0), RUN_RNG).compile().as_text()
+    assert _phases_in(text) == phases
+
+
+def test_scheduler_jits_carry_phase_scopes(setup):
+    """The scheduler's dispatch runs the local step, the wires and the
+    row gather; its apply runs the combine and the row scatter."""
+    task, batch_fn = setup
+    # the curvature stream is round-synchronous: off under semisync
+    fed = _fed(comm=dataclasses.replace(WIRES, hessian_compressor="off"),
+               sched=SchedConfig(discipline="semisync"))
+    eng = FedEngine(task, fed)
+    sched = VirtualScheduler(eng, batch_fn)
+    state = eng.init(jax.random.PRNGKey(2))
+    idx = jnp.arange(2, dtype=jnp.int32)
+    version = jnp.asarray(0, jnp.int32)
+    args = (state, batch_fn(0), idx, RUN_RNG, version)
+    text = sched._dispatch_fn.lower(*args).compile().as_text()
+    assert _phases_in(text) == ALL_PHASES - {"combine"}
+    (wires, stats, ef, opt, _, dnm, dnef, _, _) = sched._dispatch_fn(
+        *args)
+    text = sched._apply_fn.lower(
+        state, wires, stats, jnp.ones((2,), jnp.float32), idx, ef, opt,
+        dnm, dnef).compile().as_text()
+    assert _phases_in(text) == {"combine", "rows"}
+
+
+def test_phase_rejects_unknown_name():
+    with pytest.raises(ValueError, match="unknown phase"):
+        obs.phase("forward")
 
 
 # ------------------------------------------------- trace contexts
