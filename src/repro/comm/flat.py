@@ -2,7 +2,9 @@
 the flat-resident engine refactor, the canonical **in-round
 representation** of all client-visible state (params, Sophia m/h,
 EF residuals, downlink replicas; docs/architecture.md "Memory
-layout").
+layout") outside the local loop.  The local loop runs in the model's
+leaf layout: `unpack_leaves` / `pack_leaves` cross between the two
+once per client-round, each buffer keeping its resident dtype.
 
 Every leaf of the pytree is flattened to fp32, concatenated,
 zero-padded and reshaped to a (rows, cols) buffer.  Rows double as
@@ -311,6 +313,60 @@ def zeros(spec: FlatSpec, lead: Tuple[int, ...] = (),
     return jnp.zeros(tuple(lead) + (spec.rows, spec.cols), dtype)
 
 
+def _row_runs(spec: FlatSpec) -> Tuple[Tuple[int, int, int, int], ...]:
+    """``(first leaf, end leaf, first row, rows)`` of each run of
+    consecutive leaves whose flat span starts and ends on a row
+    boundary (the last run ends at the pad).  Leaves a multiple of
+    ``cols`` long are runs of their own, so `unpack_leaves` and
+    `pack_leaves` move them as whole row blocks and never build the
+    whole model as one flat vector."""
+    runs, first, start, off = [], 0, 0, 0
+    for i, sz in enumerate(spec.sizes):
+        off += sz
+        if off % spec.cols == 0 or i == len(spec.sizes) - 1:
+            r0 = start // spec.cols
+            runs.append((first, i + 1, r0, -(-off // spec.cols) - r0))
+            first, start = i + 1, off
+    return tuple(runs)
+
+
+def unpack_leaves(flat: jnp.ndarray, spec: FlatSpec):
+    """(..., rows, cols) buffer -> pytree of (..., *leaf_shape) leaves
+    in the buffer's OWN dtype, leading (e.g. per-client) axes kept.
+
+    The layout crossing of the local loops (`FedEngine._local_sophia_
+    flat`): it runs once per client-round, and the loop then carries
+    the leaves in their resident dtype.  Followed by `model_view`, it
+    gives what `unpack` gives."""
+    lead = flat.shape[:-2]
+    out: List[jnp.ndarray] = []
+    for i, j, r0, n in _row_runs(spec):
+        v = flat[..., r0:r0 + n, :].reshape(lead + (-1,))
+        off = 0
+        for sz, shp in zip(spec.sizes[i:j], spec.shapes[i:j]):
+            out.append(v[..., off:off + sz].reshape(lead + shp))
+            off += sz
+    return jax.tree_util.tree_unflatten(spec.treedef, out)
+
+
+def pack_leaves(tree, spec: FlatSpec) -> jnp.ndarray:
+    """Inverse of `unpack_leaves`: leaves of one dtype, with the same
+    leading axes, -> (..., rows, cols) buffer in that dtype (zero pad
+    at the tail): `pack` without its fp32 detour, so equal to
+    `pack(tree, spec, dtype)` wherever that dtype holds the leaves
+    exactly."""
+    leaves = jax.tree_util.tree_flatten(tree)[0]
+    l0 = leaves[0]
+    lead = l0.shape[:l0.ndim - len(spec.shapes[0])]
+    blocks = []
+    for i, j, r0, n in _row_runs(spec):
+        v = jnp.concatenate([l.reshape(lead + (-1,)) for l in leaves[i:j]],
+                            axis=-1)
+        pad = [(0, 0)] * len(lead) + [(0, n * spec.cols - v.shape[-1])]
+        blocks.append(jnp.pad(v, pad).reshape(lead + (n, spec.cols)))
+    return jnp.concatenate(blocks, axis=-2)
+
+
 def pack(tree, spec: FlatSpec, dtype=jnp.float32) -> jnp.ndarray:
     """pytree -> (rows, cols) wire buffer (zero pad at the tail).
 
@@ -339,6 +395,14 @@ def unpack(flat: jnp.ndarray, spec: FlatSpec):
         out.append(v[off:off + sz].reshape(shp).astype(dt))
         off += sz
     return jax.tree_util.tree_unflatten(spec.treedef, out)
+
+
+def model_view(tree, spec: FlatSpec):
+    """Each leaf of a leaf-layout tree (any resident dtype) cast to the
+    model's own dtype; a no-op where the two agree."""
+    return jax.tree_util.tree_unflatten(spec.treedef, [
+        l.astype(dt) for l, dt in
+        zip(jax.tree_util.tree_leaves(tree), spec.dtypes)])
 
 
 def repack(flat: jnp.ndarray, from_spec: FlatSpec,

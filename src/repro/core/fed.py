@@ -13,18 +13,21 @@ Optimizers: fed_sophia (the paper), fedavg, done, fedadam, fedyogi.
 Memory layout (docs/architecture.md "Memory layout"): the engine is
 **flat-resident** — the packed (rows, cols) fp32 wire buffer of
 `repro.comm.flat` is the canonical in-round representation of every
-piece of client-visible state: the round-start model, each client's
-evolving theta, the Sophia m/h EMAs (stored across rounds as
-(C, rows, cols) arrays), GNB estimates, uplink EF residuals and
-downlink replicas.  Pytrees are materialized only at the loss/grad
-boundary — one `unpack` view feeds `value_and_grad`, one `pack` lays
-the returned grads back — so the fused Pallas kernels and the wire
-compressors consume state that is *already* in their layout, the
-uplink delta is a flat subtraction, and the hessian stream reads
-``opt.h`` without conversion.  Leaf flattening order is frozen
+piece of client-visible state outside the local loop: the round-start
+model, each client's trained theta, the Sophia m/h EMAs (stored across
+rounds as (C, rows, cols) arrays), uplink EF residuals and downlink
+replicas.  The wire compressors consume state that is *already* in
+their layout, the uplink delta is a flat subtraction, and the hessian
+stream reads ``opt.h`` without conversion.  The local loop runs in the
+model's leaf layout: theta and m/h cross from the packed buffers into
+leaves (each in its resident dtype) once at the loop's start and back
+once at its end, so the forward/backward, the GNB estimate and the
+per-leaf Sophia kernel launches of every local step see leaves and
+never pack or unpack.  Leaf flattening order is frozen
 (`flat.FlatSpec`), which makes the flat round bit-identical to the
 historical pytree engine for fp32 models (tests/test_flat_engine.py
-pins this per config).
+pins this per config, and the leaf-layout loop against the loop that
+crossed the layout at every step).
 
 Device residency (docs/architecture.md "Memory layout: the life of a
 round"): the engine goes one step further than in-round flatness —
@@ -94,6 +97,7 @@ from typing import Any, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.comm import accounting, downlink as cdown, flat as cflat
 from repro.comm.compressors import (make_compressor, make_stream_compressor,
@@ -141,8 +145,20 @@ class CommRuntime(NamedTuple):
         return self.comp_h is not None
 
 
+def _auto(s: NamedSharding, lead=()) -> NamedSharding:
+    """``s``, with ``lead`` prepended to its spec, over the same devices
+    with every mesh axis Auto: the round leaves its shardings to GSPMD
+    (the launcher jits it with in/out shardings), and a constraint
+    inside it may not name an Explicit axis."""
+    m = s.mesh
+    auto = Mesh(m.devices, m.axis_names,
+                axis_types=(AxisType.Auto,) * len(m.axis_names))
+    return NamedSharding(auto, P(*lead, *s.spec))
+
+
 class FedEngine:
-    def __init__(self, task, fed: FedConfig, gather_shardings=None):
+    def __init__(self, task, fed: FedConfig, gather_shardings=None,
+                 carry_shardings=None):
         self.task = task
         self.fed = fed
         if fed.comm.hessian_enabled and not (
@@ -184,6 +200,14 @@ class FedEngine:
         # params to it at each local step lowers to the per-step weight
         # all-gather that defines FSDP/ZeRO-3.
         self.gather_shardings = gather_shardings
+        # The local loops carry a client's model and its Sophia m/h as
+        # the model's leaves, unpacked once per client-round.  A packed
+        # buffer's sharding does not survive that unpack (its cols are
+        # no axis of a leaf), so a multi-device launch states how the
+        # carried leaves are stored: one NamedSharding per leaf of ONE
+        # client's model (the batched loops leave their leading client
+        # axis to GSPMD).  None: no constraint.
+        self.carry_shardings = carry_shardings
         # comm_runtime memoization: specs/compressors are pure static
         # metadata, keyed on the params' avals (the engine's CommConfig
         # is immutable, so it needs no key component)
@@ -242,8 +266,19 @@ class FedEngine:
     def _gathered(self, params):
         if self.gather_shardings is None:
             return params
-        return jax.tree.map(jax.lax.with_sharding_constraint, params,
-                            self.gather_shardings)
+        return jax.tree.map(
+            lambda x, s: jax.lax.with_sharding_constraint(x, _auto(s)),
+            params, self.gather_shardings)
+
+    def _carried(self, tree, batched: bool = False):
+        """A local loop's leaf-layout carry (theta, m or h) held to
+        `carry_shardings`; unchanged without them."""
+        if self.carry_shardings is None:
+            return tree
+        lead = (P.UNCONSTRAINED,) if batched else ()
+        return jax.tree.map(
+            lambda x, s: jax.lax.with_sharding_constraint(x, _auto(s, lead)),
+            tree, self.carry_shardings)
 
     def _stateful(self) -> bool:
         """Persistent per-client Sophia state lives in the engine state
@@ -272,15 +307,17 @@ class FedEngine:
             body, init, (jnp.arange(n), mb))
         return loss, grads
 
-    def _flat_value_and_grad(self, theta, batch, spec, rng=None):
-        """The loss/grad boundary of the flat-resident engine: ONE
-        unpack materializes the pytree view for `value_and_grad`, ONE
-        pack lays the grads back into wire layout.  Also returns the
-        (gathered) pytree view so callers needing it (GNB refresh)
-        reuse the same unpack."""
-        pg = self._gathered(cflat.unpack(theta, spec))
+    def _flat_value_and_grad(self, t, batch, spec, rng=None):
+        """The local step's loss/grad boundary.  ``t`` is the client's
+        model in the leaf layout the local loop carries (each leaf in
+        its resident dtype, unpacked once per client-round); the view
+        fed to `value_and_grad` is a per-leaf cast to the model's own
+        dtypes, gathered for FSDP.  Nothing is packed or unpacked
+        here: the grads stay leaves in the model's dtypes.  Also
+        returns the (gathered) view, which the GNB refresh reuses."""
+        pg = self._gathered(cflat.model_view(t, spec))
         loss, grads = self._value_and_grad(self.task.loss, pg, batch, rng)
-        return loss, cflat.pack(grads, spec), pg
+        return loss, grads, pg
 
     # ------------------------------------------------------------------ init
     def init(self, key) -> Dict[str, Any]:
@@ -505,16 +542,17 @@ class FedEngine:
         received model -> fused uplink delta encode/decode [-> hessian-
         EMA encode/decode].
 
-        Everything stays in wire layout: ``theta`` is the packed server
-        model (canonical ``rt.spec`` geometry; ``theta_dn`` the same
-        coordinates in the downlink geometry, None when that stream is
-        off), the received replica *is* the local-training start state,
-        and the uplink delta is a flat subtraction inside
-        `Compressor.encode_delta`.  Gathered resident rows flow in
-        UN-upcast (`CommConfig.state_dtype`): the kernels upcast loads
-        to fp32 in-VMEM and jnp promotion covers the flat arithmetic;
-        callers downcast the returned rows on the scatter back
-        (`_store`).  For fp32 state every cast is a no-op.
+        Everything but the local loop stays in wire layout: ``theta``
+        is the packed server model (canonical ``rt.spec`` geometry;
+        ``theta_dn`` the same coordinates in the downlink geometry, None
+        when that stream is off), the received replica *is* the
+        local-training start state, and the uplink delta is a flat
+        subtraction inside `Compressor.encode_delta`.  Gathered
+        resident rows flow in UN-upcast (`CommConfig.state_dtype`): the
+        kernels upcast loads to fp32 in-VMEM and jnp promotion covers
+        the flat arithmetic; callers downcast the returned rows on the
+        scatter back (`_store`).  For fp32 state every cast is a
+        no-op.
 
         Returns ``(xhat, stat, ef_new, opt_new, loss, dnm_new,
         dnef_new, h_hat, h_stat)`` with ``None`` for inactive pieces.
@@ -588,6 +626,10 @@ class FedEngine:
             starts = theta
         t, opt, losses = self._local_update_flat_batched(
             rt.spec, starts, opts, batches, crngs, round_idx, lr)
+        # the draws after the local loop wait for it: XLA would
+        # otherwise draw the uplink noise beside the downlink's, before
+        # the loop, and hold a model-sized buffer through it
+        crngs, t = jax.lax.optimization_barrier((crngs, t))
         with phase("wire"):
             xhat, stat, ef_new = rt.comp.encode_delta_batched(
                 jax.vmap(lambda k: jax.random.fold_in(k, 0xC0))(crngs),
@@ -640,14 +682,29 @@ class FedEngine:
         return outs
 
     # ------------------------------------------- local client training (flat)
+    # Every local loop crosses between the packed layout and the
+    # model's leaf layout once per client-round: theta (in `fed.grad`)
+    # and the Sophia m/h (in `fed.sophia`) are unpacked into leaves in
+    # their resident dtypes at the loop's start and packed at its end.
+    # The scan carries leaves, so gradients, GNB estimates and the
+    # per-leaf Sophia kernel launches never touch the packed layout;
+    # the wires, combine and resident state see the same packed
+    # buffers.  Every op in the loop is per coordinate, so op by op the
+    # results are bitwise those of a loop that packed at every step
+    # (compiled, XLA:CPU may contract multiply-adds across the new
+    # fusions differently: last-ulp differences).
     def _local_sophia_flat(self, spec, theta, m, h, batch, round_idx, rng,
                            lr):
-        """Flat-resident Sophia local loop: theta/m/h are (rows, cols)
-        wire buffers for the whole scan; the pytree exists only as the
-        per-iteration `value_and_grad` view (plus the GNB estimate on
-        refresh iterations, packed inside its lax.cond)."""
+        """Flat-resident Sophia local loop for one client: theta/m/h
+        arrive and leave as packed (rows, cols) wire buffers and cross
+        into the leaf layout once (see above)."""
         fed = self.fed
         task = self.task
+        with phase("grad"):
+            t0 = self._carried(cflat.unpack_leaves(theta, spec))
+        with phase("sophia"):
+            m0 = self._carried(cflat.unpack_leaves(m, spec))
+            h0 = self._carried(cflat.unpack_leaves(h, spec))
 
         # round mode (Alg. 1 line 9 literal: refresh when k mod tau == 0):
         # the GNB estimate uses the round-start params, so it hoists out of
@@ -657,13 +714,14 @@ class FedEngine:
         if round_mode:
             do_h_round = (round_idx % fed.tau) == 0
             with phase("gnb"):
+                pg0 = self._gathered(cflat.model_view(t0, spec))
                 h_hat_round = jax.lax.cond(
                     do_h_round,
-                    lambda: cflat.pack(gnb_estimate(
-                        task, self._gathered(cflat.unpack(theta, spec)),
-                        batch, jax.random.fold_in(rng, 0x7FFFFFFF),
-                        vg_fn=self._value_and_grad), spec),
-                    lambda: cflat.zeros(spec))
+                    lambda: gnb_estimate(
+                        task, pg0, batch,
+                        jax.random.fold_in(rng, 0x7FFFFFFF),
+                        vg_fn=self._value_and_grad),
+                    lambda: tree_zeros_like(pg0))
 
         def step(carry, j):
             t, m_, h_ = carry
@@ -679,64 +737,75 @@ class FedEngine:
                 with phase("gnb"):
                     hh = jax.lax.cond(
                         do_h,
-                        lambda: cflat.pack(gnb_estimate(
-                            task, pg, batch, rng_j,
-                            vg_fn=self._value_and_grad), spec),
-                        lambda: cflat.zeros(spec))
+                        lambda: gnb_estimate(task, pg, batch, rng_j,
+                                             vg_fn=self._value_and_grad),
+                        lambda: tree_zeros_like(pg))
             with phase("sophia"):
                 t, m_, h_ = sophia.sophia_step_flat(
                     t, m_, h_, g, hh, do_h,
                     lr=lr, beta1=fed.beta1, beta2=fed.beta2, rho=fed.rho,
                     eps=fed.eps, weight_decay=fed.weight_decay,
                     use_pallas=fed.use_pallas)
-            return (t, m_, h_), loss
+            return tuple(map(self._carried, (t, m_, h_))), loss
 
-        (theta, m, h), losses = jax.lax.scan(
-            step, (theta, m, h), jnp.arange(fed.local_iters))
+        (t, m, h), losses = jax.lax.scan(
+            step, (t0, m0, h0), jnp.arange(fed.local_iters))
+        with phase("grad"):
+            theta = cflat.pack_leaves(t, spec)
+        with phase("sophia"):
+            m, h = cflat.pack_leaves(m, spec), cflat.pack_leaves(h, spec)
         return theta, m, h, jnp.mean(losses)
 
     def _local_sophia_flat_batched(self, spec, theta, m, h, batches,
                                    round_idx, rngs, lr):
         """`_local_sophia_flat` for N clients at once: ONE scan over
         local iterations whose body vmaps the loss/grad boundary and
-        feeds the (N, rows, cols) state stacks to a single batched
-        Sophia kernel launch per iteration.  ``theta`` may be the
-        shared (rows, cols) start model or a per-client (N, rows,
-        cols) stack (downlink replicas).  scan(vmap(grad)) computes
-        exactly what vmap(scan(grad)) would, so this is bitwise equal
-        to vmapping the per-client loop."""
+        feeds the leaves (each with a leading client axis N) to a
+        single batched Sophia kernel launch per leaf and iteration.
+        ``theta`` may be the shared (rows, cols) start model or a
+        per-client (N, rows, cols) stack (downlink replicas); m/h are
+        (N, rows, cols).  scan(vmap(grad)) computes exactly what
+        vmap(scan(grad)) would, so this is bitwise equal to vmapping
+        the per-client loop."""
         fed = self.fed
         task = self.task
         N = rngs.shape[0]
+        shared = theta.ndim == 2
+        with phase("grad"):
+            ts = self._carried(cflat.unpack_leaves(theta, spec),
+                               batched=not shared)
+        with phase("sophia"):
+            m0 = self._carried(cflat.unpack_leaves(m, spec), batched=True)
+            h0 = self._carried(cflat.unpack_leaves(h, spec), batched=True)
 
         round_mode = fed.hessian_every_unit == "round"
         if round_mode:
             do_h_round = (round_idx % fed.tau) == 0
-            if theta.ndim == 3:
-                def gnb_round():
-                    return jax.vmap(
-                        lambda t, b, r: cflat.pack(gnb_estimate(
-                            task, self._gathered(cflat.unpack(t, spec)),
-                            b, jax.random.fold_in(r, 0x7FFFFFFF),
-                            vg_fn=self._value_and_grad), spec)
-                    )(theta, batches, rngs)
-            else:
-                # shared start model: ONE unpacked view feeds every
-                # client's estimator (what vmap hoists anyway)
-                with phase("gnb"):
-                    pg0 = self._gathered(cflat.unpack(theta, spec))
-
-                def gnb_round():
-                    return jax.vmap(
-                        lambda b, r: cflat.pack(gnb_estimate(
-                            task, pg0, b,
-                            jax.random.fold_in(r, 0x7FFFFFFF),
-                            vg_fn=self._value_and_grad), spec)
-                    )(batches, rngs)
             with phase("gnb"):
-                h_hat_round = jax.lax.cond(
-                    do_h_round, gnb_round,
-                    lambda: cflat.zeros(spec, (N,)))
+                def estimate(pg, b, r):
+                    return gnb_estimate(
+                        task, pg, b, jax.random.fold_in(r, 0x7FFFFFFF),
+                        vg_fn=self._value_and_grad)
+
+                if shared:
+                    # shared start model: ONE view feeds every client's
+                    # estimator (what vmap hoists anyway)
+                    pg0 = self._gathered(cflat.model_view(ts, spec))
+
+                    def gnb_round():
+                        return jax.vmap(functools.partial(estimate, pg0))(
+                            batches, rngs)
+                else:
+                    def gnb_round():
+                        return jax.vmap(lambda t, b, r: estimate(
+                            self._gathered(cflat.model_view(t, spec)), b, r)
+                        )(ts, batches, rngs)
+
+                def no_gnb():
+                    return jax.tree_util.tree_unflatten(spec.treedef, [
+                        jnp.zeros((N,) + shp, dt)
+                        for shp, dt in zip(spec.shapes, spec.dtypes)])
+                h_hat_round = jax.lax.cond(do_h_round, gnb_round, no_gnb)
 
         def step(carry, j):
             t, m_, h_ = carry
@@ -754,34 +823,52 @@ class FedEngine:
                     hh = jax.lax.cond(
                         do_h,
                         lambda: jax.vmap(
-                            lambda pg, bb, r: cflat.pack(gnb_estimate(
+                            lambda pg, bb, r: gnb_estimate(
                                 task, pg, bb, jax.random.fold_in(r, j),
-                                vg_fn=self._value_and_grad), spec)
+                                vg_fn=self._value_and_grad)
                         )(pgs, batches, rngs),
-                        lambda: cflat.zeros(spec, (N,)))
+                        lambda: tree_zeros_like(pgs))
             with phase("sophia"):
                 t, m_, h_ = sophia.sophia_step_flat(
                     t, m_, h_, g, hh, do_h,
                     lr=lr, beta1=fed.beta1, beta2=fed.beta2, rho=fed.rho,
                     eps=fed.eps, weight_decay=fed.weight_decay,
-                    use_pallas=fed.use_pallas)
-            return (t, m_, h_), losses
+                    use_pallas=fed.use_pallas, batched=True)
+            return tuple(self._carried(x, batched=True)
+                         for x in (t, m_, h_)), losses
 
-        t0 = (theta if theta.ndim == 3
-              else jnp.broadcast_to(theta[None], (N,) + theta.shape))
-        (theta, m, h), losses = jax.lax.scan(
-            step, (t0, m, h), jnp.arange(fed.local_iters))
+        with phase("grad"):
+            t0 = (self._carried(jax.tree.map(
+                lambda x: jnp.broadcast_to(x[None], (N,) + x.shape), ts),
+                batched=True) if shared else ts)
+        (t, m, h), losses = jax.lax.scan(
+            step, (t0, m0, h0), jnp.arange(fed.local_iters))
+        with phase("grad"):
+            theta = cflat.pack_leaves(t, spec)
+        with phase("sophia"):
+            m, h = cflat.pack_leaves(m, spec), cflat.pack_leaves(h, spec)
         return theta, m, h, jnp.mean(losses, axis=0)
 
+    @staticmethod
+    def _sgd_step(t, g, lr):
+        """theta - lr * g per leaf, in fp32, stored in theta's dtype."""
+        return jax.tree.map(
+            lambda x, gg: (x - lr * gg.astype(jnp.float32)).astype(x.dtype),
+            t, g)
+
     def _local_sgd_flat(self, spec, theta, batch, rng, lr):
-        """Flat-resident local SGD: the update is one flat axpy."""
+        """Flat-resident local SGD: the same once-per-client-round
+        crossing as the Sophia loop; the update is a per-leaf axpy."""
         def step(t, j):
             with phase("grad"):
                 loss, g, _ = self._flat_value_and_grad(t, batch, spec)
-            return t - lr * g, loss
+            return self._carried(self._sgd_step(t, g, lr)), loss
 
-        theta, losses = jax.lax.scan(step, theta,
-                                     jnp.arange(self.fed.local_iters))
+        with phase("grad"):
+            t0 = self._carried(cflat.unpack_leaves(theta, spec))
+        t, losses = jax.lax.scan(step, t0, jnp.arange(self.fed.local_iters))
+        with phase("grad"):
+            theta = cflat.pack_leaves(t, spec)
         return theta, jnp.mean(losses)
 
     def _local_sgd_flat_batched(self, spec, theta, batches, rngs, lr):
@@ -794,12 +881,18 @@ class FedEngine:
                 losses, g, _ = jax.vmap(
                     lambda tt, bb: self._flat_value_and_grad(tt, bb, spec)
                 )(t, batches)
-            return t - lr * g, losses
+            t = self._carried(self._sgd_step(t, g, lr), batched=True)
+            return t, losses
 
-        t0 = (theta if theta.ndim == 3
-              else jnp.broadcast_to(theta[None], (N,) + theta.shape))
-        theta, losses = jax.lax.scan(step, t0,
-                                     jnp.arange(self.fed.local_iters))
+        with phase("grad"):
+            t0 = cflat.unpack_leaves(theta, spec)
+            if theta.ndim == 2:
+                t0 = jax.tree.map(
+                    lambda x: jnp.broadcast_to(x[None], (N,) + x.shape), t0)
+            t0 = self._carried(t0, batched=True)
+        t, losses = jax.lax.scan(step, t0, jnp.arange(self.fed.local_iters))
+        with phase("grad"):
+            theta = cflat.pack_leaves(t, spec)
         return theta, jnp.mean(losses, axis=0)
 
     def _local_sgd(self, params, batch, rng, lr):

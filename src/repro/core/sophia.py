@@ -5,11 +5,11 @@ Two twins with identical per-coordinate semantics:
 * the pytree form (`sophia_step` and friends) — the reference the
   paper-facing tests pin, still selectable onto the fused Pallas
   kernel via ``use_pallas``;
-* the flat form (`sophia_step_flat`) — one packed (rows, cols) fp32
-  buffer per state stream, consumed by the flat-resident round engine
-  (`repro.core.fed`), where the kernel path needs **zero** layout
-  conversion because the engine already holds theta/m/h in the wire
-  layout (docs/architecture.md "Memory layout").
+* the flat form (`sophia_step_flat`) — the flat-resident round
+  engine's local step (`repro.core.fed`): theta/m/h in the leaf layout
+  the local loop carries for a whole client-round, each leaf in its
+  resident dtype, so the kernel path (one launch per leaf) needs no
+  layout conversion per step (docs/architecture.md "Memory layout").
 """
 from __future__ import annotations
 
@@ -65,12 +65,12 @@ def sophia_step(params, grads, state: SophiaState, h_hat, do_h_update,
     do_h_update: traced bool — h-EMA applied under lax.cond-style select.
     """
     if use_pallas:
-        # single fused Pallas pass: m-EMA, gated h-EMA, decay, clip, update
-        from repro.kernels.ops import sophia_fused_step
-        params, m, h = sophia_fused_step(
+        # one fused Pallas pass per leaf: m-EMA, gated h-EMA, decay,
+        # clip, update
+        params, m, h = sophia_step_flat(
             params, state.m, state.h, grads, h_hat, do_h_update,
             lr=lr, beta1=beta1, beta2=beta2, rho=rho, eps=eps,
-            weight_decay=weight_decay)
+            weight_decay=weight_decay, use_pallas=True)
         return params, SophiaState(m=m, h=h)
     m = update_m(state.m, grads, beta1)
     h_new = update_h(state.h, h_hat, beta2)
@@ -83,38 +83,44 @@ def sophia_step(params, grads, state: SophiaState, h_hat, do_h_update,
 
 def sophia_step_flat(theta, m, h, grads, h_hat, do_h_update, *, lr, beta1,
                      beta2, rho, eps, weight_decay,
-                     use_pallas: bool = False):
-    """`sophia_step` over packed (rows, cols) wire buffers.
+                     use_pallas: bool = False, batched: bool = False):
+    """`sophia_step` over the flat engine's local-loop state, one array
+    leaf at a time: the local loops carry theta/m/h as the model's
+    leaves in their resident dtypes, and a packed (rows, cols) buffer
+    is a one-leaf tree.  ``batched``: every leaf carries a leading
+    client axis.
 
-    Bit-identical per coordinate to the pytree form for fp32 buffers
-    (the ops are all elementwise; the zero pad tail is a fixed point,
-    so packed state stays valid wire buffers across iterations).
-    With ``use_pallas`` the buffers feed the fused kernel directly —
-    no pack/unpack.  Follows the kernel layer's dtype contract: bf16
-    resident buffers (`CommConfig.state_dtype`) are upcast to fp32
-    for the arithmetic and the results stored back in each input's
-    dtype (no-op casts for fp32).  Returns ``(theta, m, h)``.
-
-    Also accepts packed (clients, rows, cols) stacks: the pure path
-    is elementwise and shape-agnostic, and the kernel path dispatches
-    to the client-batched launch (`sophia_update_batched`) — ONE
-    kernel call for the whole cohort, bitwise equal to per-client
-    calls.
+    Bit-identical per coordinate to the pytree form and to one update
+    over the packed buffer (the ops are all elementwise; the packed
+    zero pad tail is a fixed point).  With ``use_pallas`` each leaf
+    feeds the fused kernel (`sophia_update_leaf`: viewed 2-D, no
+    relayout; the client-batched launch when ``batched``).  Follows
+    the kernel layer's dtype contract: narrow resident leaves (bf16,
+    fp8) are upcast to fp32 for the arithmetic and the results stored
+    back in each input's dtype (no-op casts for fp32).  Returns
+    ``(theta, m, h)`` as trees shaped like ``theta``.
     """
     if use_pallas:
-        from repro.kernels.sophia_update import (sophia_update_batched,
-                                                 sophia_update_flat)
-        fn = sophia_update_batched if theta.ndim == 3 else sophia_update_flat
-        return fn(
-            theta, m, h, grads, h_hat, do_h_update, lr, beta1=beta1,
-            beta2=beta2, rho=rho, eps=eps, weight_decay=weight_decay)
-    out_dt = (theta.dtype, m.dtype, h.dtype)
-    theta, m, h, grads, h_hat = (x.astype(jnp.float32)
-                                 for x in (theta, m, h, grads, h_hat))
-    m = beta1 * m + (1.0 - beta1) * grads                          # Eq. 9
-    h = jnp.where(do_h_update,
-                  beta2 * h + (1.0 - beta2) * h_hat, h)            # Eq. 10
-    theta = theta - lr * weight_decay * theta                      # line 15
-    step = clip(m / jnp.maximum(h, eps), rho)                      # Eq. 11
-    return ((theta - lr * step).astype(out_dt[0]),                 # line 16
-            m.astype(out_dt[1]), h.astype(out_dt[2]))
+        from repro.kernels.sophia_update import sophia_update_leaf
+
+        def leaf(t, mm, hh, g, e):
+            return sophia_update_leaf(
+                t, mm, hh, g, e, do_h_update, lr, batched=batched,
+                beta1=beta1, beta2=beta2, rho=rho, eps=eps,
+                weight_decay=weight_decay)
+    else:
+        def leaf(t, mm, hh, g, e):
+            out_dt = (t.dtype, mm.dtype, hh.dtype)
+            t, mm, hh, g, e = (x.astype(jnp.float32)
+                               for x in (t, mm, hh, g, e))
+            mm = beta1 * mm + (1.0 - beta1) * g                    # Eq. 9
+            hh = jnp.where(do_h_update,
+                           beta2 * hh + (1.0 - beta2) * e, hh)     # Eq. 10
+            t = t - lr * weight_decay * t                          # line 15
+            step = clip(mm / jnp.maximum(hh, eps), rho)            # Eq. 11
+            return ((t - lr * step).astype(out_dt[0]),             # line 16
+                    mm.astype(out_dt[1]), hh.astype(out_dt[2]))
+    treedef = jax.tree.structure(theta)
+    outs = [leaf(*xs) for xs in zip(*(jax.tree.leaves(x) for x in
+                                      (theta, m, h, grads, h_hat)))]
+    return tuple(jax.tree.unflatten(treedef, list(o)) for o in zip(*outs))

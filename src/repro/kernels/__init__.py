@@ -6,7 +6,9 @@ that is *already* in their layout, so the kernel path performs zero
 pytree<->flat conversion (gated by ``make bench-engine-smoke``):
 
 * `sophia_update.sophia_update_flat` — the fused Sophia local
-  iteration (m-EMA, gated h-EMA, decay, clip, step) over theta/m/h/g.
+  iteration (m-EMA, gated h-EMA, decay, clip, step) over theta/m/h/g;
+  the engine's local loop launches it on each model leaf, viewed 2-D
+  (`sophia_update_leaf`).
 * `quantize.quant_roundtrip_flat` / `uplink_roundtrip_flat` /
   `broadcast_roundtrip_flat` / `sign_roundtrip_flat` /
   `topk_threshold_flat` — the wire round-trips of the comm streams

@@ -6,13 +6,17 @@ elementwise ops (m-EMA, h-EMA select, max, div, clip, decay, axpy); fusing
 them into one VMEM pass reads each of the 4 input streams once and writes
 3 output streams once — the HBM-roofline optimum for this op.
 
-TPU mapping: parameters are flattened and tiled into (8, 1024)-multiples
-(fp32 VREG tiling is (8,128); 1024 lanes amortises grid overhead).
-Each grid step owns one (BLOCK_R, BLOCK_C) tile in VMEM.
+TPU mapping: the engine launches the kernel once per model leaf
+(`sophia_update_leaf`), each leaf viewed as (R, C) by collapsing its
+leading dimensions — a bitcast, since the TPU tiles only the last two
+(fp32 VREG tiling is (8,128)).  Each grid step owns one (block_r,
+block_c) tile in VMEM: the tuned tile, with its column block snapped
+to a divisor of the leaf's width (`_snap_blocks`).
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +26,25 @@ from repro.kernels import interpret_mode, tuning
 
 BLOCK_R = 256
 BLOCK_C = 1024
+#: lane width of a TPU vreg: a column block is a multiple of it or the
+#: whole width
+LANES = 128
+
+
+def _snap_blocks(R: int, C: int, br: int, bc: int, tile: int):
+    """Fit a tuned (br, bc) block (clamped to the operand) to an (R, C)
+    leaf of ``tile`` = the tuned tile's element count.  A leaf no
+    larger than one tile is one block.  Otherwise the column block is
+    the multiple of `LANES` nearest ``bc`` that divides C, so no block
+    is ragged: minicpm's widths 2304 and 5760 both take 1152 where the
+    tuned 1024 divides neither.  A width that is not a multiple of
+    `LANES` keeps ``bc`` (the last block is masked)."""
+    if R * C <= tile:
+        return R, C
+    if C % bc and C % LANES == 0:
+        bc = min((d for d in range(LANES, C + 1, LANES) if C % d == 0),
+                 key=lambda d: (abs(d - bc), -d))
+    return br, bc
 
 
 def _sophia_kernel(theta_ref, m_ref, h_ref, g_ref, hhat_ref, flags_ref,
@@ -67,6 +90,8 @@ def sophia_update_flat(theta, m, h, g, h_hat, do_h, lr, *, beta1, beta2,
     """
     R, C = theta.shape
     br, bc = tuning.blocks_2d("sophia_update", R, C, dtype=theta.dtype)
+    br, bc = _snap_blocks(R, C, br, bc, math.prod(tuning.tile(
+        "sophia_update", dtype=theta.dtype)[1:]))
     grid = (pl.cdiv(R, br), pl.cdiv(C, bc))
     flags = jnp.stack([jnp.asarray(do_h, jnp.float32).reshape(()),
                        jnp.asarray(lr, jnp.float32).reshape(())]
@@ -110,6 +135,9 @@ def sophia_update_batched(theta, m, h, g, h_hat, do_h, lr, *, beta1,
     N, R, C = theta.shape
     bn, br, bc = tuning.blocks_for("sophia_update", N, R, C,
                                    override=blocks, dtype=theta.dtype)
+    if blocks is None:
+        br, bc = _snap_blocks(R, C, br, bc, math.prod(tuning.tile(
+            "sophia_update", N, dtype=theta.dtype)[1:]))
     grid = (pl.cdiv(N, bn), pl.cdiv(R, br), pl.cdiv(C, bc))
     flags = jnp.stack([jnp.asarray(do_h, jnp.float32).reshape(()),
                        jnp.asarray(lr, jnp.float32).reshape(())]
@@ -132,3 +160,24 @@ def sophia_update_batched(theta, m, h, g, h_hat, do_h, lr, *, beta1,
             out_shape=out_shape,
             interpret=interpret_mode(interpret),
         )(theta, m, h, g, h_hat, flags)
+
+
+def sophia_update_leaf(theta, m, h, g, h_hat, do_h, lr, *, batched,
+                       beta1, beta2, rho, eps, weight_decay,
+                       interpret=None):
+    """The fused update over one model leaf of any rank, in the leaf's
+    own shape: the leaf is viewed as (R, C) by collapsing its leading
+    dimensions — (N, R, C) behind a leading client axis when
+    ``batched`` — and launched through `sophia_update_flat` (or
+    `sophia_update_batched`).  A vector leaf is one row, a scalar one
+    element.  Per coordinate the same kernel body as one launch over
+    the packed buffer, so the values are bitwise those
+    (tests/test_kernel_conformance.py)."""
+    shape = theta.shape
+    lead, body = (shape[:1], shape[1:]) if batched else ((), shape)
+    view = lead + (math.prod(body[:-1]), body[-1] if body else 1)
+    fn = sophia_update_batched if batched else sophia_update_flat
+    outs = fn(*(x.reshape(view) for x in (theta, m, h, g, h_hat)), do_h,
+              lr, beta1=beta1, beta2=beta2, rho=rho, eps=eps,
+              weight_decay=weight_decay, interpret=interpret)
+    return tuple(o.reshape(shape) for o in outs)

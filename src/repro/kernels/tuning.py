@@ -129,6 +129,16 @@ def _lookup(kernel: str, dtype, n: int) -> Dict[str, int]:
     return table.get(kernel, {})
 
 
+def tile(kernel: str, n: int = 1, dtype=None) -> Tuple[int, int, int]:
+    """The (bn, br, bc) block ``kernel`` is tuned to for ``n`` clients
+    and ``dtype``, before any clamping to an operand (the most
+    specific committed entry, else the safe defaults)."""
+    e = _lookup(kernel, dtype, n)
+    return (e.get("block_n", DEFAULT_BLOCK_N),
+            e.get("block_r", DEFAULT_BLOCK_R),
+            e.get("block_c", DEFAULT_BLOCK_C))
+
+
 def blocks_for(kernel: str, n: int, r: int, c: int,
                override: Optional[Tuple[int, int, int]] = None,
                dtype=None) -> Tuple[int, int, int]:
@@ -139,13 +149,8 @@ def blocks_for(kernel: str, n: int, r: int, c: int,
     defaults; always clamped to the operand dims.  ``dtype`` is the
     primary operand's storage dtype (the resident state the kernel
     loads) — omit it to resolve the dtype-agnostic entry."""
-    if override is not None:
-        bn, br, bc = override
-    else:
-        e = _lookup(kernel, dtype, n)
-        bn = e.get("block_n", DEFAULT_BLOCK_N)
-        br = e.get("block_r", DEFAULT_BLOCK_R)
-        bc = e.get("block_c", DEFAULT_BLOCK_C)
+    bn, br, bc = (override if override is not None
+                  else tile(kernel, n, dtype=dtype))
     return (max(1, min(int(bn), n)), max(1, min(int(br), r)),
             max(1, min(int(bc), c)))
 

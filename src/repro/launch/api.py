@@ -141,15 +141,22 @@ def build_train(arch_id: str, mesh, *, reduced: bool = False,
         fed = dataclasses.replace(fed, comm=comm)
     task = T.LMTask(cfg)
     seq_fed0 = fed.strategy == "sequential"
+    p_struct = jax.eval_shape(lambda k: T.init_lm(k, cfg),
+                              jax.random.PRNGKey(0))
     gather_sh = None
     if seq_fed0 and fsdp_gather:
         # FSDP storage sharding is (model x data); every USE of the params
         # must see the model-only sharding or GSPMD replicates the
         # batch-sharded activations over data instead (see FedEngine).
-        p_struct = jax.eval_shape(lambda k: T.init_lm(k, cfg),
-                                  jax.random.PRNGKey(0))
         gather_sh = S.param_shardings(cfg, mesh, p_struct, fsdp_axes=None)
-    engine = FedEngine(task, fed, gather_shardings=gather_sh)
+    # the local loops carry each client's model and Sophia m/h as leaves,
+    # stored as the params are: over the model axes, and under FSDP the
+    # data axes too
+    carry_sh = S.param_shardings(
+        cfg, mesh, p_struct,
+        fsdp_axes=data_axes(mesh) if seq_fed0 else None)
+    engine = FedEngine(task, fed, gather_shardings=gather_sh,
+                       carry_shardings=carry_sh)
 
     C = fed.num_clients
     caxes = client_axes(mesh)

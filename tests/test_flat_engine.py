@@ -590,3 +590,304 @@ def test_flat_round_bit_identical_opbyop_variants(setup, kw):
     for le, lr_ in losses:
         assert le == lr_, (kw, losses)
     _assert_state_bit_identical(eng, s_eng, s_ref)
+
+
+# ------------------------------------------- leaf-layout local loops
+class PerStepPackedLoops:
+    """The local Sophia loops as they stood before they ran in the
+    model's leaf layout: theta/m/h stay packed (rows, cols) buffers
+    through the whole scan, and every step crosses the layout twice —
+    ONE `unpack` gives `value_and_grad` its view and ONE `pack` lays
+    the grads back (the GNB estimate is packed inside its `lax.cond`)
+    — before one fused update over the packed buffers.  A frozen copy
+    of `FedEngine._local_sophia_flat(_batched)` and
+    `sophia.sophia_step_flat` of that engine, the reference of the
+    leaf-layout loops below."""
+
+    def __init__(self, eng: FedEngine):
+        self.eng = eng
+        self.fed = eng.fed
+        self.task = eng.task
+
+    @staticmethod
+    def _pack(tree, spec):
+        v = jnp.concatenate([l.reshape(-1).astype(jnp.float32)
+                             for l in jax.tree.leaves(tree)])
+        return jnp.pad(v, (0, spec.padded - spec.total)).reshape(
+            spec.rows, spec.cols)
+
+    @staticmethod
+    def _unpack(flat, spec):
+        v = flat.reshape(-1)[:spec.total]
+        out, off = [], 0
+        for sz, shp, dt in zip(spec.sizes, spec.shapes, spec.dtypes):
+            out.append(v[off:off + sz].reshape(shp).astype(dt))
+            off += sz
+        return jax.tree_util.tree_unflatten(spec.treedef, out)
+
+    def _value_and_grad(self, theta, batch, spec):
+        pg = self.eng._gathered(self._unpack(theta, spec))
+        loss, grads = self.eng._value_and_grad(self.task.loss, pg, batch)
+        return loss, self._pack(grads, spec), pg
+
+    def _update(self, theta, m, h, grads, h_hat, do_h, lr):
+        fed = self.fed
+        hp = dict(beta1=fed.beta1, beta2=fed.beta2, rho=fed.rho,
+                  eps=fed.eps, weight_decay=fed.weight_decay)
+        if fed.use_pallas:
+            from repro.kernels.sophia_update import (sophia_update_batched,
+                                                     sophia_update_flat)
+            fn = (sophia_update_batched if theta.ndim == 3
+                  else sophia_update_flat)
+            return fn(theta, m, h, grads, h_hat, do_h, lr, **hp)
+        out_dt = (theta.dtype, m.dtype, h.dtype)
+        theta, m, h, grads, h_hat = (x.astype(jnp.float32)
+                                     for x in (theta, m, h, grads, h_hat))
+        m = hp["beta1"] * m + (1.0 - hp["beta1"]) * grads
+        h = jnp.where(do_h, hp["beta2"] * h + (1.0 - hp["beta2"]) * h_hat,
+                      h)
+        theta = theta - lr * hp["weight_decay"] * theta
+        step = sophia.clip(m / jnp.maximum(h, hp["eps"]), hp["rho"])
+        return ((theta - lr * step).astype(out_dt[0]),
+                m.astype(out_dt[1]), h.astype(out_dt[2]))
+
+    def local_sophia(self, spec, theta, m, h, batch, round_idx, rng, lr):
+        fed, task, vg = self.fed, self.task, self.eng._value_and_grad
+        round_mode = fed.hessian_every_unit == "round"
+        if round_mode:
+            do_h_round = (round_idx % fed.tau) == 0
+            h_hat_round = jax.lax.cond(
+                do_h_round,
+                lambda: self._pack(gnb_estimate(
+                    task, self.eng._gathered(self._unpack(theta, spec)),
+                    batch, jax.random.fold_in(rng, 0x7FFFFFFF),
+                    vg_fn=vg), spec),
+                lambda: cflat.zeros(spec))
+
+        def step(carry, j):
+            t, m_, h_ = carry
+            loss, g, pg = self._value_and_grad(t, batch, spec)
+            if round_mode:
+                do_h = do_h_round & (j == 0)
+                hh = h_hat_round
+            else:
+                do_h = ((round_idx * fed.local_iters + j) % fed.tau) == 0
+                rng_j = jax.random.fold_in(rng, j)
+                hh = jax.lax.cond(
+                    do_h,
+                    lambda: self._pack(gnb_estimate(
+                        task, pg, batch, rng_j, vg_fn=vg), spec),
+                    lambda: cflat.zeros(spec))
+            return self._update(t, m_, h_, g, hh, do_h, lr), loss
+
+        (theta, m, h), losses = jax.lax.scan(
+            step, (theta, m, h), jnp.arange(fed.local_iters))
+        return theta, m, h, jnp.mean(losses)
+
+    def local_sophia_batched(self, spec, theta, m, h, batches, round_idx,
+                             rngs, lr):
+        fed, task, vg = self.fed, self.task, self.eng._value_and_grad
+        N = rngs.shape[0]
+        round_mode = fed.hessian_every_unit == "round"
+        if round_mode:
+            do_h_round = (round_idx % fed.tau) == 0
+            if theta.ndim == 3:
+                def gnb_round():
+                    return jax.vmap(
+                        lambda t, b, r: self._pack(gnb_estimate(
+                            task, self.eng._gathered(self._unpack(t, spec)),
+                            b, jax.random.fold_in(r, 0x7FFFFFFF),
+                            vg_fn=vg), spec))(theta, batches, rngs)
+            else:
+                pg0 = self.eng._gathered(self._unpack(theta, spec))
+
+                def gnb_round():
+                    return jax.vmap(
+                        lambda b, r: self._pack(gnb_estimate(
+                            task, pg0, b, jax.random.fold_in(r, 0x7FFFFFFF),
+                            vg_fn=vg), spec))(batches, rngs)
+            h_hat_round = jax.lax.cond(do_h_round, gnb_round,
+                                       lambda: cflat.zeros(spec, (N,)))
+
+        def step(carry, j):
+            t, m_, h_ = carry
+            losses, g, pgs = jax.vmap(
+                lambda tt, bb: self._value_and_grad(tt, bb, spec))(
+                    t, batches)
+            if round_mode:
+                do_h = do_h_round & (j == 0)
+                hh = h_hat_round
+            else:
+                do_h = ((round_idx * fed.local_iters + j) % fed.tau) == 0
+                hh = jax.lax.cond(
+                    do_h,
+                    lambda: jax.vmap(
+                        lambda pg, bb, r: self._pack(gnb_estimate(
+                            task, pg, bb, jax.random.fold_in(r, j),
+                            vg_fn=vg), spec))(pgs, batches, rngs),
+                    lambda: cflat.zeros(spec, (N,)))
+            return self._update(t, m_, h_, g, hh, do_h, lr), losses
+
+        t0 = (theta if theta.ndim == 3
+              else jnp.broadcast_to(theta[None], (N,) + theta.shape))
+        (theta, m, h), losses = jax.lax.scan(
+            step, (t0, m, h), jnp.arange(fed.local_iters))
+        return theta, m, h, jnp.mean(losses, axis=0)
+
+
+class BF16MLP(MLPTask):
+    """The MLP with bfloat16 parameters: the engine's model view is then
+    a real cast of the resident theta, as it is for the benchmarked
+    language model."""
+
+    def init(self, key):
+        return jax.tree.map(lambda x: x.astype(jnp.bfloat16),
+                            super().init(key))
+
+
+#: resident state: (model dtype is bf16?, theta, m, h)
+LOOP_STATES = {
+    "fp32": (False, jnp.float32, jnp.float32, jnp.float32),
+    "bf16-fp8": (True, jnp.float32, jnp.float8_e4m3fn, jnp.float8_e5m2),
+}
+
+
+def _loop_inputs(task, batches, state, clients):
+    """(engine, spec, theta, m, h, batches, rngs) of a local loop over
+    ``clients`` (None: the one-client loop); the start models, m and h
+    differ between clients and carry a zero pad tail."""
+    bf16_model, t_dt, m_dt, h_dt = LOOP_STATES[state]
+    if bf16_model:
+        task = BF16MLP(hidden=task.hidden)
+    params = task.init(jax.random.PRNGKey(3))
+    spec = cflat.flat_spec(params, cols=128)
+    n = clients or 1
+    ks = jax.random.split(jax.random.PRNGKey(4), 3 * n)
+
+    def rand_tree(k, scale, absval=False):
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        t = treedef.unflatten([
+            scale * jax.random.normal(jax.random.fold_in(k, i), x.shape)
+            for i, x in enumerate(leaves)])
+        return jax.tree.map(jnp.abs, t) if absval else t
+
+    theta = jnp.stack([cflat.pack(jax.tree.map(
+        lambda p, d: p.astype(jnp.float32) + d, params,
+        rand_tree(ks[i], 0.01)), spec, t_dt) for i in range(n)])
+    m = jnp.stack([cflat.pack(rand_tree(ks[n + i], 1e-3), spec, m_dt)
+                   for i in range(n)])
+    h = jnp.stack([cflat.pack(rand_tree(ks[2 * n + i], 1e-3, True), spec,
+                              h_dt) for i in range(n)])
+    rngs = jax.vmap(lambda i: jax.random.fold_in(jax.random.PRNGKey(5), i))(
+        jnp.arange(n))
+    b = jax.tree.map(lambda x: x[:n], batches)
+    if clients is None:
+        theta, m, h, b, rngs = (jax.tree.map(lambda x: x[0], a)
+                                for a in (theta, m, h, b, rngs))
+    return task, spec, theta, m, h, b, rngs
+
+
+@pytest.mark.parametrize("state", sorted(LOOP_STATES))
+@pytest.mark.parametrize("clients", [None, 2], ids=["one", "two"])
+@pytest.mark.parametrize("mode", ["step", "round"])
+def test_leaf_loop_matches_per_step_packed_loop(setup, mode, clients,
+                                                state):
+    """The local Sophia loop that crosses into the leaf layout once per
+    client-round is BITWISE the frozen loop that crossed at every step
+    (op-by-op, so no fusion can differ): theta/m/h and the losses, in
+    step and round GNB mode, for one client and for two clients with
+    their own start models, fp32 and bf16/fp8 resident state."""
+    task, batches = setup
+    task, spec, theta, m, h, b, rngs = _loop_inputs(task, batches, state,
+                                                    clients)
+    fed = FedConfig(num_clients=4, local_iters=3, optimizer="fed_sophia",
+                    lr=0.01, tau=2, hessian_every_unit=mode)
+    eng = FedEngine(task, fed)
+    ref = PerStepPackedLoops(eng)
+    round_idx = jnp.asarray(0, jnp.int32)
+    with jax.disable_jit():
+        if clients is None:
+            got = eng._local_sophia_flat(spec, theta, m, h, b, round_idx,
+                                         rngs, 0.01)
+            want = ref.local_sophia(spec, theta, m, h, b, round_idx, rngs,
+                                    0.01)
+        else:
+            got = eng._local_sophia_flat_batched(spec, theta, m, h, b,
+                                                 round_idx, rngs, 0.01)
+            want = ref.local_sophia_batched(spec, theta, m, h, b,
+                                            round_idx, rngs, 0.01)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    # the GNB refresh ran and moved h: the comparison saw curvature
+    assert not np.array_equal(np.asarray(got[2], np.float32),
+                              np.asarray(h, np.float32))
+
+
+def test_leaf_loop_matches_per_step_packed_loop_pallas(setup):
+    """The kernel path: per-leaf client-batched launches against one
+    launch over the packed stacks, bitwise under jit (each launch is an
+    opaque unit), bf16 model with fp8 m/h."""
+    task, batches = setup
+    task, spec, theta, m, h, b, rngs = _loop_inputs(task, batches,
+                                                    "bf16-fp8", 2)
+    fed = FedConfig(num_clients=4, local_iters=3, optimizer="fed_sophia",
+                    lr=0.01, tau=2, use_pallas=True)
+    eng = FedEngine(task, fed)
+    ref = PerStepPackedLoops(eng)
+    args = (spec, theta, m, h, b, jnp.asarray(0, jnp.int32), rngs, 0.01)
+    got = jax.jit(eng._local_sophia_flat_batched, static_argnums=0)(*args)
+    want = jax.jit(ref.local_sophia_batched, static_argnums=0)(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def _crossings(jaxpr, spec, in_scan=False, out=None):
+    """Layout crossings in ``jaxpr``, keyed by whether they sit inside
+    a scan body: packs (a concatenate to the packed buffer or to its
+    ``spec.total`` flat coordinates) and unpacks (a reshape that
+    flattens rows of ``spec.cols`` packed coordinates)."""
+    out = out if out is not None else {True: 0, False: 0}
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        shapes_in = [getattr(v.aval, "shape", ()) for v in eqn.invars]
+        shape_out = getattr(eqn.outvars[0].aval, "shape", ())
+        packs = (name == "concatenate" and shape_out
+                 and (shape_out[-1] == spec.total
+                      or shape_out[-2:] == (spec.rows, spec.cols)))
+        unpacks = (name == "reshape" and shapes_in
+                   and len(shapes_in[0]) >= 2
+                   and shapes_in[0][-1] == spec.cols
+                   and len(shape_out) == len(shapes_in[0]) - 1)
+        out[in_scan] += bool(packs) + bool(unpacks)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _crossings(sub, spec, in_scan or name == "scan", out)
+    return out
+
+
+def test_local_scan_holds_no_layout_crossing(setup):
+    """A packed-resident Sophia round, traced at J = 1 and J = 5: the
+    local scan's body packs and unpacks nothing, and the round crosses
+    the layout as often at J = 5 as at J = 1 (once a client-round)."""
+    task, batches = setup
+    counts = {}
+    for J in (1, 5):
+        fed = FedConfig(num_clients=4, local_iters=J,
+                        optimizer="fed_sophia", lr=0.01, tau=J,
+                        use_pallas=True,
+                        comm=CommConfig(compressor="int8",
+                                        downlink_compressor="int8",
+                                        use_pallas=True,
+                                        state_dtype="bfloat16",
+                                        moment_dtype="float8_e4m3fn",
+                                        hessian_dtype="float8_e5m2"))
+        eng = FedEngine(task, fed)
+        state = eng.pack_state(eng.init(jax.random.PRNGKey(2)))
+        spec = eng.runtime_for(state["params"]).spec
+        closed = jax.make_jaxpr(eng.round)(state, batches,
+                                           jax.random.PRNGKey(7))
+        scans = [e for e in closed.jaxpr.eqns if e.primitive.name == "scan"]
+        assert [e.params["length"] for e in scans] == [J]
+        counts[J] = _crossings(closed.jaxpr, spec)
+        assert counts[J][True] == 0, counts
+    assert counts[1][False] == counts[5][False] > 0, counts

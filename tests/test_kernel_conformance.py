@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.comm import flat as cflat
 from repro.kernels import ref
 from repro.kernels.quantize import (broadcast_roundtrip_batched,
                                     broadcast_roundtrip_flat,
@@ -39,7 +40,8 @@ from repro.kernels.quantize import (broadcast_roundtrip_batched,
                                     uplink_roundtrip_batched,
                                     uplink_roundtrip_flat)
 from repro.kernels.sophia_update import (sophia_update_batched,
-                                         sophia_update_flat)
+                                         sophia_update_flat,
+                                         sophia_update_leaf)
 from repro.kernels.stale_accum import stale_accum_flat
 
 DTYPES = [jnp.float32, jnp.bfloat16, jnp.float8_e4m3fn,
@@ -53,6 +55,73 @@ FAST_BLOCKS = [None, RAGGED]
 QMAX = 7
 HP = dict(beta1=0.9, beta2=0.95, rho=0.04, eps=1e-12, weight_decay=1e-4)
 LR = 3e-3
+
+
+#: model leaves of the per-leaf Sophia launches: a 3-D leaf, a width
+#: that is not a multiple of 1024 and spans more than one tuned tile
+#: (its column block is snapped to 1152), a vector, an odd matrix
+LEAF_SHAPES = [(2, 24, 384), (256, 2304), (100,), (3, 7)]
+
+
+def _leaf_operands(dtype, n):
+    """(spec, packed (n, rows, cols) theta, m, h, g, h_hat) over
+    `LEAF_SHAPES` as the engine holds them: theta in ``dtype``, m e4m3,
+    h e5m2, gradients and GNB estimate bf16, zero pad tail."""
+    spec = cflat.flat_spec([jax.ShapeDtypeStruct(s, jnp.float32)
+                            for s in LEAF_SHAPES])
+    live = (jnp.arange(spec.padded) < spec.total).reshape(spec.rows,
+                                                         spec.cols)
+    ks = jax.random.split(jax.random.PRNGKey(9), 5)
+
+    def nrm(k, s, dt, pos=False):
+        x = s * jax.random.normal(k, (n, spec.rows, spec.cols))
+        return jnp.where(live, jnp.abs(x) if pos else x, 0).astype(dt)
+
+    return spec, (nrm(ks[0], 1.0, dtype),
+                  nrm(ks[1], 0.1, jnp.float8_e4m3fn),
+                  nrm(ks[2], 0.01, jnp.float8_e5m2, pos=True),
+                  nrm(ks[3], 0.5, jnp.bfloat16),
+                  nrm(ks[4], 0.02, jnp.bfloat16, pos=True))
+
+
+def _by_leaf(spec, outs):
+    """(theta, m, h) packed results -> [(theta, m, h) of each leaf]."""
+    return list(zip(*(cflat.unpack_leaves(o, spec) for o in outs)))
+
+
+def _leaf_cases(dtype, n):
+    """The per-leaf launches of the engine's local loop (each leaf
+    viewed 2-D, with and without the client axis) against one launch
+    per client over the packed buffer, and the oracle over it."""
+    def per_leaf(batched):
+        spec, packed = _leaf_operands(dtype, n)
+        leaves = [cflat.unpack_leaves(x, spec) for x in packed]
+        launch = lambda *xs: sophia_update_leaf(
+            *xs, 1.0, LR, batched=batched, interpret=True, **HP)
+        if batched:
+            return [launch(*xs) for xs in zip(*leaves)]
+        return [tuple(jnp.stack(o) for o in zip(*(
+            launch(*(x[i] for x in xs)) for i in range(n))))
+            for xs in zip(*leaves)]
+
+    def packed_per_client():
+        spec, packed = _leaf_operands(dtype, n)
+        outs = [sophia_update_flat(*(x[i] for x in packed), 1.0, LR,
+                                   interpret=True, **HP)
+                for i in range(n)]
+        return _by_leaf(spec, [jnp.stack(o) for o in zip(*outs)])
+
+    def oracle():
+        spec, packed = _leaf_operands(dtype, n)
+        return _by_leaf(spec, ref.sophia_update_ref(*packed, 1.0, lr=LR,
+                                                    **HP))
+
+    return {
+        "sophia_update_leaf": (lambda b: per_leaf(True),
+                               packed_per_client, oracle),
+        "sophia_update_leaf_2d": (lambda b: per_leaf(False),
+                                  packed_per_client, oracle),
+    }
 
 
 def _leaves(out):
@@ -77,10 +146,14 @@ ULP_TOL = {jnp.dtype(jnp.bfloat16): 2 ** -8,
 
 
 def _close_to_ref(out, refd, dtype):
-    band = ULP_TOL.get(jnp.dtype(dtype))
-    tol = (dict(rtol=band, atol=band) if band
-           else dict(rtol=1e-6, atol=1e-6))
+    """Each output within the band of its storage format, or of the
+    case's ``dtype`` where that is the narrower (the per-leaf Sophia
+    case stores m e4m3 and h e5m2 whatever theta's dtype)."""
     for a, b in zip(_leaves(out), _leaves(refd)):
+        band = max(ULP_TOL.get(jnp.dtype(dtype), 0),
+                   ULP_TOL.get(jnp.dtype(a.dtype), 0))
+        tol = (dict(rtol=band, atol=band) if band
+               else dict(rtol=1e-6, atol=1e-6))
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32), **tol)
 
@@ -121,6 +194,7 @@ def _cases(dtype, n, r, c):
         return looped
 
     return {
+        **_leaf_cases(dtype, n),
         "quant_roundtrip": (
             lambda b: quant_roundtrip_batched(
                 x, noise, scales, qmax=QMAX, interpret=True, blocks=b),
@@ -317,3 +391,25 @@ def test_sweep_batched_equals_looped(shape, blocks, dtype):
     kernel, every block candidate, both dtypes — always bitwise."""
     for kernel, (batched, looped, _) in _cases(dtype, *shape).items():
         _bitwise(batched(blocks), looped())
+
+
+@pytest.mark.parametrize("R,C", [(122880, 2304), (4608, 5760),
+                                 (11520, 2304), (4608, 2304), (2, 2304),
+                                 (1, 2304), (300, 1000)])
+def test_sophia_leaf_blocks(R, C):
+    """The Sophia kernel's block on a leaf's (R, C) view: a leaf within
+    one tuned tile is one block; a wider one takes the lane-aligned
+    divisor of its width nearest the tuned column block (1152 for
+    minicpm's 2304 and 5760), so no block is ragged; a width that is
+    not lane-aligned keeps the tuned block."""
+    from repro.kernels import sophia_update as su, tuning
+    _, tr, tc = tuning.tile("sophia_update", dtype=jnp.float32)
+    br, bc = tuning.blocks_2d("sophia_update", R, C, dtype=jnp.float32)
+    br, bc = su._snap_blocks(R, C, br, bc, tr * tc)
+    if R * C <= tr * tc:
+        assert (br, bc) == (R, C)
+    elif C % su.LANES:
+        assert (br, bc) == (min(tr, R), min(tc, C))
+    else:
+        assert C % bc == 0 and bc % su.LANES == 0 and br == min(tr, R)
+        assert bc == (1152 if C in (2304, 5760) else tc)

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.ops import sophia_fused_step
+from repro.core.sophia import sophia_step_flat
 from repro.kernels.ref import sophia_update_ref, uplink_roundtrip_ref
 from repro.kernels.sophia_update import sophia_update_flat
 
@@ -71,9 +71,10 @@ def test_fused_step_traced_lr_and_flag():
 
     @jax.jit
     def step(p, lr, do_h):
-        return sophia_fused_step(p, jax.tree.map(jnp.zeros_like, p),
-                                 jax.tree.map(jnp.zeros_like, p),
-                                 grads, h_hat, do_h, lr=lr, **HP)
+        return sophia_step_flat(p, jax.tree.map(jnp.zeros_like, p),
+                                jax.tree.map(jnp.zeros_like, p),
+                                grads, h_hat, do_h, lr=lr, **HP,
+                                use_pallas=True)
 
     p1, m1, h1 = step(params, jnp.asarray(1e-2), jnp.asarray(1.0))
     p2, m2, h2 = step(params, jnp.asarray(0.0), jnp.asarray(0.0))

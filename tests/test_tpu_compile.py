@@ -4,7 +4,9 @@ Every kernel family of `repro.kernels.KERNELS` is lowered through
 Mosaic (``interpret=False``) and compiled for one chip of a described
 ``v5e:2x2`` topology at the packed wire geometry of minicpm-2b cut to
 2 layers — the model the chip smoke test trains — with the committed
-tuning geometry, at fp32, bf16 and e4m3 resident state.  The compiler
+tuning geometry, at fp32, bf16 and e4m3 resident state; the Sophia
+kernel also at each of that model's leaves, as the local loop launches
+it.  The compiler
 refuses what the interpreter never checks: blocks that break the
 (8, 128) tiling rule, and tiles whose double-buffered operands exceed
 the scoped VMEM limit.  Nothing runs, so values are the conformance
@@ -30,7 +32,8 @@ from repro.kernels.quantize import (broadcast_roundtrip_batched,
                                     topk_threshold_batched,
                                     uplink_roundtrip_batched)
 from repro.kernels.robust_agg import robust_agg_flat
-from repro.kernels.sophia_update import sophia_update_batched
+from repro.kernels.sophia_update import (sophia_update_batched,
+                                         sophia_update_leaf)
 from repro.kernels.stale_accum import stale_accum_flat
 from repro.models import transformer as T
 
@@ -144,3 +147,35 @@ def test_wire_geometry_is_minicpm_2_layers(wire_geometry):
     cfg = configs.get_model_config("minicpm-2b").with_depth(2)
     assert dataclasses.replace(cfg, num_layers=40) == \
         configs.get_model_config("minicpm-2b")
+
+
+#: minicpm-2b's leaves at 2 layers; the local loop launches the Sophia
+#: kernel once per leaf, viewed (R, C) by collapsing the leading dims
+MINICPM_LEAVES = {
+    "embed": (122880, 2304),
+    "w_gate_up": (2, 2304, 5760),
+    "w_down": (2, 5760, 2304),
+    "wq_wk_wv_wo": (2, 2304, 2304),
+    "ln1_ln2": (2, 2304),
+    "final_norm": (2304,),
+}
+
+
+@pytest.mark.parametrize("leaf", sorted(MINICPM_LEAVES))
+def test_sophia_leaf_launch_compiles_for_v5e(leaf, one_chip,
+                                             no_persistent_cache):
+    """One client's per-leaf Sophia launch as the benchmarked round
+    makes it: fp32 theta, e4m3 m, e5m2 h, bf16 gradients and GNB
+    estimate, each leaf with the client axis in front."""
+    shape = (1,) + MINICPM_LEAVES[leaf]
+
+    def put(dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    args = (put(F32), put(jnp.float8_e4m3fn), put(jnp.float8_e5m2),
+            put(jnp.bfloat16), put(jnp.bfloat16))
+    compiled = jax.jit(lambda t, m, h, g, hh: sophia_update_leaf(
+        t, m, h, g, hh, True, 1e-3, batched=True, beta1=0.9, beta2=0.95,
+        rho=0.04, eps=1e-12, weight_decay=1e-4, interpret=False)
+    ).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
