@@ -6,11 +6,14 @@ and the output check.  Everything a cell is made of is found by name:
 * ``bench/workloads/<cell>.json`` holds the limits of its output check;
 * ``bench/configs/<config>.json`` and ``bench/traffic/<mix>.json`` hold
   the model, the engine and the traffic;
+* ``bench/models/<arch>.py``, for the configuration's ``arch``, holds
+  everything that knows the architecture (``bench/models/__init__.py``);
 * ``bench/metrics/<metric>.py`` reads one per-layer metric.
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
 import importlib.util
 import json
 import os
@@ -22,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import check, engine, reference, tracing, work
+from bench import check, engine, models, reference, tracing, work
 from bench.traffic.tokens import batch_pool
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
@@ -48,6 +51,14 @@ class Cell:
     limits: Dict[str, float]
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
+    #: the architecture's module, ``bench/models/<arch>.py``
+    model: Any
+
+
+def cell_names(root: str = ROOT) -> List[str]:
+    """The cells of ``<root>/BENCHMARK.json``, in its order."""
+    spec = read_json(os.path.join(root, "BENCHMARK.json"))
+    return [w["name"] for w in spec["workloads"]]
 
 
 def _reports(metric: Dict, cell: str) -> bool:
@@ -72,7 +83,8 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     per_layer = [m for m in spec["per_layer"]
                  if m["moves"] in moved and _reports(m, name)]
     return Cell(name=name, workload=wl, cfg=cfg, traffic=traffic,
-                limits=cell["limits"], end_to_end=e2e, per_layer=per_layer)
+                limits=cell["limits"], end_to_end=e2e, per_layer=per_layer,
+                model=models.load(cfg["arch"], root))
 
 
 def keys(seed: int) -> Dict[str, Any]:
@@ -112,8 +124,7 @@ class Program:
                  plant: Optional[Callable] = None):
         self.cell = cell
         self.k = keys(seed)
-        self.wire = reference.Wire.of(reference.Model.from_config(cell.cfg),
-                                      cell.cfg["engine"]["quant_block"])
+        self.wire = reference.wire_of(cell.model, cell.cfg)
         self.plant = plant
         self.round = 0
         self.readings: Dict[str, Any] = {}
@@ -123,9 +134,11 @@ class Program:
 
     def setup(self, pool):
         """Build, then run rounds 1..STEPS through the window's own
-        compiled round and batches, reading the state as they go."""
+        compiled round and batches, reading the state as they go.  Ends
+        with a collection, so that no buffer held by a reference cycle
+        is freed, or kept, at random in the window."""
         self.pool = pool
-        sysm = engine.build(self.cell.cfg, self.cell.traffic, self.k["init"])
+        sysm = engine.build(self.cell, self.k["init"])
         w = self.wire
         if (sysm.rows, sysm.cols, sysm.total) != (w.rows, w.cols, w.total):
             raise ValueError(
@@ -159,6 +172,7 @@ class Program:
             state["params"], jax.device_put(p0)), np.float64))
         self.state = state
         self.round = check.STEPS
+        gc.collect()
 
     def step(self):
         r = self.round
@@ -170,18 +184,35 @@ class Program:
     def window(self, seconds: float):
         """Rounds back to back for ``seconds``, one in flight behind the
         one being dispatched; ends when the state is ready.  Returns
-        (rounds, elapsed seconds, losses)."""
-        losses = []
+        (rounds, elapsed seconds, losses).  Keeps in ``marks`` the
+        seconds into the window at which each dispatch returned and the
+        round before it was ready."""
+        losses, self.marks = [], []
         t0 = time.perf_counter()
         while True:
             losses.append(self.step())
+            sent = time.perf_counter() - t0
             if len(losses) > 1:
                 losses[-2].block_until_ready()
-            if time.perf_counter() - t0 >= seconds:
+            self.marks.append((sent, time.perf_counter() - t0))
+            if self.marks[-1][1] >= seconds:
                 break
         jax.block_until_ready(self.state)
         elapsed = time.perf_counter() - t0
         return len(losses), elapsed, [float(x) for x in losses]
+
+    def slowest_round(self) -> str:
+        """Where the window's host time went: the median and the longest
+        interval between two rounds' completions, and the longest
+        dispatch."""
+        ready = [r for _, r in self.marks]
+        gaps = [b - a for a, b in zip(ready, ready[1:])] or [0.0]
+        sends = [s - r for (s, _), (_, r) in zip(self.marks[1:],
+                                                 self.marks)] or [0.0]
+        k = max(range(len(gaps)), key=gaps.__getitem__)
+        return (f"round completions {float(np.median(gaps))!r} s apart, "
+                f"longest {gaps[k]!r} s (ending with round {k} of the "
+                f"window), longest dispatch {max(sends)!r} s")
 
     def traced(self, directory: str, rounds: int = TRACE_ROUNDS):
         """Trace ``rounds`` more rounds into ``directory``."""
@@ -221,7 +252,7 @@ def reference_readings(cell: Cell, seed: int, pool,
                        lower=None) -> Dict[str, Any]:
     """The reference's numbers for the first `check.STEPS` rounds; with
     ``lower``, those of the lower-precision control."""
-    ref = reference.Reference(cell.cfg, cell.traffic, lower)
+    ref = reference.Reference(cell.model, cell.cfg, cell.traffic, lower)
     k = keys(seed)
     st = ref.init(k["init"])
     p0 = st["theta"]
@@ -251,6 +282,30 @@ class TraceContext:
     kernels: List[Dict[str, Any]]
     memory: Any
     rounds: int
+    #: the text of the compiled round the traced rounds ran
+    hlo_text: Optional[str] = None
+    _phase_ns: Dict[Any, Any] = dataclasses.field(default_factory=dict,
+                                                  repr=False)
+
+    def phase_ms(self, label: str,
+                 scopes: Dict[str, str] = tracing.PHASE_SCOPES
+                 ) -> Optional[float]:
+        """Device self time per traced round, in ms, of the scope
+        ``scopes[label]``, or with ``label`` `tracing.REST` of the busy
+        time outside every scope of ``scopes`` (label -> scope name;
+        by default the round's phases).  None where the trace, the
+        module or the scopes are missing.  Read once per set of scopes
+        and kept for the other readers."""
+        key = tuple(scopes.items())
+        if key not in self._phase_ns:
+            self._phase_ns[key] = None if self.hlo_text is None else (
+                tracing.phase_ns(self.reduction,
+                                 tracing.hlo_phases(self.hlo_text, scopes),
+                                 tuple(scopes)))
+        per = self._phase_ns[key]
+        if per is None:
+            return None
+        return per[label] / self.reduction.devices / self.rounds * 1e-6
 
     def kernel_roofline(self, family: str) -> Optional[float]:
         """Bytes the family's launches move, at the HBM peak, over
@@ -289,8 +344,12 @@ def trace_context(cell: Cell, prog: Program, first: int, directory: str,
                   peaks) -> TraceContext:
     red = tracing.reduce(tracing.load(directory))
     compiled = prog.compiled
+    text = compiled.as_text()
+    per_token = cell.model.train_flops_per_token(cell.cfg,
+                                                 cell.traffic["seq"])
     return TraceContext(
         cell=cell, reduction=red, peaks=peaks,
-        flops=work.round_flops(cell.cfg, cell.traffic, TRACE_ROUNDS, first),
-        kernels=work.kernel_calls(compiled.as_text()),
-        memory=compiled.memory_analysis(), rounds=TRACE_ROUNDS)
+        flops=work.round_flops(per_token, cell.traffic, TRACE_ROUNDS, first),
+        kernels=work.kernel_calls(text),
+        memory=compiled.memory_analysis(), rounds=TRACE_ROUNDS,
+        hlo_text=text)

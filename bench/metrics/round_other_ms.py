@@ -2,8 +2,8 @@
 local scan's carry and slicing, rng folding, the metrics outputs, and
 any operation no scope covers), in ms per round: the busy time per
 round less the six phases."""
-from bench import phases
+from bench import tracing
 
 
 def read(ctx):
-    return phases.phase_ms(ctx, phases.REST)
+    return ctx.phase_ms(tracing.REST)

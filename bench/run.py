@@ -23,6 +23,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import gc  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -99,7 +100,10 @@ def main(argv=None):
     # the TPU runtime holds loaded programs' temporaries in memory it
     # reserves apart from the buffers in use: the window's footprint is
     # the two together.  Set-up's transients (the state built, then
-    # packed) show only in the allocator's peak, reported apart.
+    # packed) show only in the allocator's peak, reported apart.  A
+    # collection first, so that buffers held by reference cycles are
+    # freed in every run and not only where the collector happened to run
+    gc.collect()
     stats = dev.memory_stats() or {}
     footprint = (int(stats.get("bytes_in_use", 0))
                  + int(stats.get("bytes_reserved", 0)))
@@ -116,7 +120,9 @@ def main(argv=None):
                                       "unit": m["unit"]}
     print(f"bench: {cell.name} seed {args.seed} setup_s {setup_s!r} "
           f"window {rounds} rounds in {elapsed!r} s, "
-          f"{counter.count} compilations in the window; memory {stats}",
+          f"{counter.count} compilations in the window; "
+          f"{prog.slowest_round()}; "
+          f"{len(jax.live_arrays())} live arrays; memory {stats}",
           file=sys.stderr, flush=True)
 
     readings = prog.readings

@@ -8,11 +8,11 @@ import os
 import jax
 import pytest
 
-from bench import engine, harness, phases, tracing
+from bench import engine, harness, tracing
 from bench.tests import tiny
 
 FIXTURE = os.path.join(harness.BENCH, "fixtures", "cpu_trace.xplane.pb")
-CELLS = ("minicpm-2b.l2.sync-c1-s2048", "minicpm-2b.l2.v8.sync-c2-s512")
+CELLS = harness.cell_names()
 READERS = ("local_grad_ms", "gnb_ms", "sophia_step_ms", "wire_ms",
            "combine_ms", "client_rows_ms", "round_other_ms")
 
@@ -71,17 +71,17 @@ ENTRY %main.5 (p.1: f32[8]) -> (f32[8], f32[8]) {
 
 
 def test_phase_of_takes_the_innermost_fed_scope():
-    assert phases.phase_of("jit(round)/fed.wire/fed.rows/copy") == "rows"
-    assert phases.phase_of("jit(round)/vmap(fed.grad)/dot") == "grad"
-    assert phases.phase_of(
+    assert tracing.phase_of("jit(round)/fed.wire/fed.rows/copy") == "rows"
+    assert tracing.phase_of("jit(round)/vmap(fed.grad)/dot") == "grad"
+    assert tracing.phase_of(
         "jit(round)/fed.sophia/pallas:sophia_update_batched/x") == "sophia"
-    assert phases.phase_of("jit(round)/while/body/closed_call") is None
+    assert tracing.phase_of("jit(round)/while/body/closed_call") is None
     # a name that only begins like a phase is none
-    assert phases.phase_of("jit(round)/fed.gradual/add") is None
+    assert tracing.phase_of("jit(round)/fed.gradual/add") is None
 
 
 def test_hlo_phases_of_a_handwritten_module():
-    got = phases.hlo_phases(HLO)
+    got = tracing.hlo_phases(HLO)
     assert got["fusion.9"] == got["add.3"] == "combine"
     assert got["pallas_sophia_update_batched.10"] == "sophia"
     assert got["gather.2"] == got["reshape.18457"] == "grad"
@@ -113,11 +113,11 @@ def test_phase_ns_closes_the_sum_with_the_rest():
                       "while.1": 3.0, "copy.398": 7.0, "copy.794": 4.0,
                       "bitcast_dynamic-update-slice_fusion.16": 6.0,
                       "dynamic-update-slice.3685": 10.0}, 100.0)
-    got = phases.phase_ns(red, phases.hlo_phases(HLO))
+    got = tracing.phase_ns(red, tracing.hlo_phases(HLO))
     assert got == {"grad": 20.0, "gnb": 6.0, "sophia": 40.0, "wire": 0.0,
                    "combine": 10.0, "rows": 17.0, "other": 7.0}
     # a module without a single fed. scope reads nothing
-    assert phases.phase_ns(red, {"fusion.9": None}) is None
+    assert tracing.phase_ns(red, {"fusion.9": None}) is None
 
 
 class _Compiled:
@@ -127,19 +127,18 @@ class _Compiled:
     def as_text(self):
         return self.text
 
+    def memory_analysis(self):
+        return None
+
 
 def _fixture_context(hlo_text):
-    """The recorded trace's context, and the run's program holding a
-    module of ``hlo_text`` (kept alive by the caller)."""
-    from jax.profiler import ProfileData
-    red = tracing.reduce(ProfileData.from_file(FIXTURE))
+    """The recorded trace's context, as `harness.trace_context` makes it
+    for a run whose compiled round is a module of ``hlo_text``."""
     cell = harness.load_cell(CELLS[0])
     prog = harness.Program.__new__(harness.Program)
     prog.cell, prog.compiled = cell, _Compiled(hlo_text)
-    ctx = harness.TraceContext(
-        cell=cell, reduction=red, peaks={}, flops=0.0, kernels=[],
-        memory=None, rounds=harness.TRACE_ROUNDS)
-    return ctx, prog
+    return harness.trace_context(cell, prog, 0, os.path.dirname(FIXTURE),
+                                 peaks={})
 
 
 def test_seven_readings_sum_to_busy_time_per_round():
@@ -151,8 +150,8 @@ def test_seven_readings_sum_to_busy_time_per_round():
            '"jit(f)/fed.wire/tanh"}\n'
            '  %dot_general.3 = f32[8]{0} dot(), metadata={op_name='
            '"jit(f)/dot_general"}\n')
-    ctx, prog = _fixture_context(hlo)
-    assert phases.compiled_text(ctx) == hlo
+    ctx = _fixture_context(hlo)
+    assert ctx.hlo_text == hlo
     got = {m: harness.read_metric(m, ctx) for m in READERS}
     red = ctx.reduction
     per_round = red.busy_ns / harness.TRACE_ROUNDS * 1e-6
@@ -164,19 +163,37 @@ def test_seven_readings_sum_to_busy_time_per_round():
     assert got["gnb_ms"] == 0.0
     # a program without the scopes names no phase: every reader is
     # silent, as where the run kept no compiled module
-    ctx, prog = _fixture_context(hlo.replace("fed.", "f."))
+    ctx = _fixture_context(hlo.replace("fed.", "f."))
     assert all(harness.read_metric(m, ctx) is None for m in READERS)
-    ctx, prog = _fixture_context(hlo)
-    prog.compiled = None
-    assert phases.compiled_text(ctx) is None
+    ctx = _fixture_context(hlo)
+    ctx.hlo_text = None
     assert all(harness.read_metric(m, ctx) is None for m in READERS)
+
+
+def test_any_named_scope_reads_its_device_time():
+    """A scope outside the round's phases (as an expert layer's would
+    be) reads its own self time per round, the rest the busy time less
+    it; a scope no instruction names reads nothing."""
+    hlo = ('  %dot_general.2 = f32[8]{0} dot(), metadata={op_name='
+           '"jit(f)/fed.grad/moe.experts/dot_general"}\n')
+    ctx = _fixture_context(hlo)
+    red = ctx.reduction
+    experts = {"experts": "moe.experts"}
+    assert ctx.phase_ms("experts", experts) == pytest.approx(
+        red.op_ns["dot_general.2"] / 3 * 1e-6)
+    assert ctx.phase_ms("experts", experts) + ctx.phase_ms(
+        tracing.REST, experts) == pytest.approx(
+            red.busy_ns / harness.TRACE_ROUNDS * 1e-6, rel=1e-12)
+    # the round's phases are read apart, on the same context
+    assert ctx.phase_ms("grad") == ctx.phase_ms("experts", experts)
+    assert ctx.phase_ms("router", {"router": "moe.router"}) is None
 
 
 def _compiled_text(name):
     c = tiny.cell(name)
     k = harness.keys(5)
     pool = harness.make_pool(c, k["data"])
-    sysm = engine.build(c.cfg, c.traffic, k["init"])
+    sysm = engine.build(c, k["init"])
     return sysm.round_fn.lower(engine.avals(sysm.state), pool[0],
                                jax.random.PRNGKey(0)).compile().as_text()
 
@@ -187,10 +204,10 @@ def test_tiny_cell_round_carries_every_phase(name, monkeypatch):
     compiles to the same program, metadata aside."""
     from repro.core import fed
     text = _compiled_text(name)
-    assert set(phases.hlo_phases(text).values()) == set(
-        phases.PHASES) | {None}
+    assert set(tracing.hlo_phases(text).values()) == set(
+        tracing.PHASES) | {None}
     monkeypatch.setattr(fed, "phase",
                         lambda name: contextlib.nullcontext())
     bare = _compiled_text(name)
-    assert not any(phases.hlo_phases(bare).values())
-    assert phases.without_metadata(text) == phases.without_metadata(bare)
+    assert not any(tracing.hlo_phases(bare).values())
+    assert tracing.without_metadata(text) == tracing.without_metadata(bare)

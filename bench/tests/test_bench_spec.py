@@ -11,12 +11,14 @@ import sys
 
 import pytest
 
-from bench import check, harness
+from bench import check, harness, reference
+from bench.tests import tiny
 
 ROOT = harness.ROOT
 SPEC = harness.read_json(os.path.join(ROOT, "BENCHMARK.json"))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SEED = 2 ** 31 + 7
 KEYS = {
     "top": {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"},
@@ -93,6 +95,47 @@ def test_new_cell_needs_only_new_files(tmp_path):
     assert "answer" in [m["name"] for m in c.per_layer]
     other = harness.load_cell(spec["workloads"][0]["name"], root=str(root))
     assert "answer" not in [m["name"] for m in other.per_layer]
+
+
+def test_new_architecture_needs_only_new_files(tmp_path):
+    """A configuration whose ``arch`` module exists only in a temporary
+    checkout (MiniCPM-2B with an untied head, a leaf the program's
+    MiniCPM cells lack) resolves to that module; its tiny run passes
+    the output check and its float8 control fails it."""
+    root = tmp_path / "root"
+    bench = root / "bench"
+    shutil.copytree(harness.BENCH, bench,
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    module = bench / "models" / "minicpm_2b_untied.py"
+    shutil.copy(os.path.join(harness.BENCH, "fixtures",
+                             "minicpm_2b_untied.py"), module)
+    spec = json.loads(json.dumps(SPEC))
+    base = spec["workloads"][0]
+    conf = next(c for c in spec["configs"] if c["name"] == base["config"])
+    cfg = dict(harness.read_json(os.path.join(ROOT, conf["file"])),
+               name="untied", arch="minicpm-2b-untied",
+               tie_word_embeddings=False)
+    (bench / "configs" / "untied.json").write_text(json.dumps(cfg))
+    name = "untied." + base["traffic"]
+    shutil.copy(bench / "workloads" / (base["name"] + ".json"),
+                bench / "workloads" / (name + ".json"))
+    spec["configs"].append(dict(conf, name="untied",
+                                file="bench/configs/untied.json"))
+    spec["workloads"].append(dict(base, name=name, config="untied"))
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    c = tiny.cell(name, root=str(root))
+    assert os.path.samefile(c.model.__file__, module)
+    wire = reference.wire_of(c.model, c.cfg)
+    assert wire.names[-1] == "['lm_head']"
+    assert c.model.program_config(c.cfg).tie_embeddings is False
+    found, ok = tiny.run(c, SEED)
+    assert ok, found
+    pool = harness.make_pool(c, harness.keys(SEED)["data"])
+    ctl = check.gaps(
+        harness.reference_readings(c, SEED, pool, "float8_e4m3fn"),
+        harness.reference_readings(c, SEED, pool))
+    assert not check.verdict(ctl, c.limits), ctl
 
 
 def _run(cwd, *extra):

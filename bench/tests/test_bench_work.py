@@ -1,5 +1,7 @@
-"""Work counts against hand values, the peaks table, and the trace
-reduction against a CPU trace recorded once and committed."""
+"""Work counts against hand values, the peaks table, the trace
+reduction against a CPU trace recorded once and committed, and the CPU
+cut of every cell.  Each model's own counts are beside its module
+(``bench/models/test_<arch>.py``)."""
 import os
 
 import pytest
@@ -8,39 +10,18 @@ from bench import harness, reference, tracing, work
 from bench.tests import tiny
 
 FIXTURE = os.path.join(harness.BENCH, "fixtures", "cpu_trace.xplane.pb")
-
-
-def _cfg(name):
-    return harness.load_cell(name).cfg
-
-
-def test_minicpm_l2_parameter_count():
-    cfg = _cfg("minicpm-2b.l2.sync-c1-s2048")
-    wire = reference.Wire.of(reference.Model.from_config(cfg), 1024)
-    # 122,880 padded rows x 2304 + 2 x (4 x 2304^2 + 3 x 2304 x 5760
-    # + 2 x 2304) + 2304
-    assert wire.total == 405_220_608
-    assert (wire.rows, wire.cols) == (395_724, 1024)
-    # the unpadded head: 122,753 x 2304, plus the layers' matrices
-    assert work.matmul_params(cfg) == 122_753 * 2304 + 2 * (
-        4 * 2304 ** 2 + 3 * 2304 * 5760)
-
-
-def test_vocab_slice_parameter_count():
-    cfg = _cfg("minicpm-2b.l2.v8.sync-c2-s512")
-    wire = reference.Wire.of(reference.Model.from_config(cfg), 1024)
-    assert wire.total == 15_360 * 2304 + 2 * (
-        4 * 2304 ** 2 + 3 * 2304 * 5760 + 2 * 2304) + 2304
+CELLS = harness.cell_names()
 
 
 def test_round_flops_count_refreshes():
-    cfg = _cfg("minicpm-2b.l2.sync-c1-s2048")
     t = {"clients": 1, "batch": 1, "seq": 2048, "local_iters": 5,
          "tau": 5}
-    per_token = 6 * work.matmul_params(cfg) + 6 * 2 * 2048 * 2304
-    assert work.round_flops(cfg, t) == 6 * 2048 * per_token
+    per_token = 1.5e9
+    assert work.round_flops(per_token, t) == 6 * 2048 * per_token
     t["tau"] = 10        # a refresh every other round
-    assert work.round_flops(cfg, t, rounds=2) == 11 * 2048 * per_token
+    assert work.round_flops(per_token, t, rounds=2) == 11 * 2048 * per_token
+    t["clients"] = 2     # and every client's tokens
+    assert work.round_flops(per_token, t, rounds=2) == 22 * 2048 * per_token
 
 
 # one batched Sophia update over 2 clients of 1,538 x 1,024 coordinates,
@@ -102,8 +83,15 @@ def test_union_merges_and_clips():
         (1, 6), (8, 9), (10, 15)]
 
 
-def test_tiny_cells_keep_the_wire_geometry():
-    for name in ("minicpm-2b.l2.sync-c1-s2048",
-                 "minicpm-2b.l2.v8.sync-c2-s512"):
-        c = tiny.cell(name)
-        assert c.cfg["hidden_size"] == 128 and c.traffic["seq"] == 32
+@pytest.mark.parametrize("name", CELLS)
+def test_tiny_cells_keep_the_wire_geometry(name):
+    """A tiny cell is its cell's files with the model cut by its module
+    and the sequence cut: the engine, the traffic mix and the limits
+    stay, so the wire keeps its block of columns."""
+    full, c = harness.load_cell(name), tiny.cell(name)
+    assert c.cfg["engine"] == full.cfg["engine"]
+    assert c.traffic == dict(full.traffic, seq=tiny.TINY_SEQ)
+    assert c.limits == full.limits
+    small = reference.wire_of(c.model, c.cfg)
+    assert small.cols == full.cfg["engine"]["quant_block"]
+    assert 0 < small.total < reference.wire_of(full.model, full.cfg).total

@@ -1,21 +1,16 @@
 """The benchmark's cells at a size a CPU test run holds: the same
-files, with the widths, the vocabulary and the sequence cut (tests
-only; the benchmark's cells keep the published widths)."""
+files, with the model cut by its module's ``tiny`` and the sequence cut
+(tests only; the benchmark's cells keep the published widths)."""
 from __future__ import annotations
 
 from bench import harness
 
-TINY_MODEL = dict(hidden_size=128, num_attention_heads=4,
-                  num_key_value_heads=4, intermediate_size=256,
-                  dim_model_base=128)
 TINY_SEQ = 32
 
 
-def cell(name: str) -> harness.Cell:
-    c = harness.load_cell(name)
-    c.cfg = dict(c.cfg, **TINY_MODEL,
-                 vocab_size=min(c.cfg["vocab_size"], 500) // (
-                     2 if "v8" in name else 1))
+def cell(name: str, root: str = harness.ROOT) -> harness.Cell:
+    c = harness.load_cell(name, root=root)
+    c.cfg = c.model.tiny(c.cfg)
     c.traffic = dict(c.traffic, seq=TINY_SEQ)
     return c
 

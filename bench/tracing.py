@@ -1,4 +1,5 @@
-"""Reduction of a profiler trace (``.xplane.pb``) to device time.
+"""Reduction of a profiler trace (``.xplane.pb``) to device time, and
+of device time to the program's named scopes.
 
 Device operations are the events of each device plane's ``XLA Ops``
 line (a TPU, where an event's name is its HLO instruction as printed,
@@ -9,18 +10,40 @@ union of the operations' intervals; an operation's time is its self
 time, less what the operations nested in it cover; the window is the
 harness's ``bench.traced`` host span; each idle gap inside it is named
 after the innermost ``bench.*`` host span that covers its midpoint.
+
+Scopes.  The program names parts of its round with device scopes
+(``jax.named_scope``; the round's phases are ``fed.<phase>``,
+`repro.obs.phase`), which the compiled module carries in each
+instruction's ``metadata={op_name="..."}``.  The trace names a device
+operation by its instruction, so the module's text maps each operation
+to a scope: the innermost of a given set of scopes in its ``op_name``
+(a fusion's metadata is its root's).  What runs inside a scope's loop or
+branch is that scope's.  Instructions the compiler makes carry no
+``op_name`` at all (layout copies, the copies and fusions it splits out
+of the program's ops): such an instruction takes the scope of what it
+fuses, else that of its users, else that of its operands, where those
+agree (`hlo_phases`).  An operation outside every scope of the set goes
+to the rest, which is the busy time less the scopes (`phase_ns`).
 """
 from __future__ import annotations
 
 import dataclasses
 import glob
 import os
+import re
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
 WINDOW_SPAN = "bench.traced"
 SPAN_PREFIX = "bench."
 OPS_LINE = "XLA Ops"
+
+#: the round's phases, as the program names its scopes
+PHASES = ("grad", "gnb", "sophia", "wire", "combine", "rows")
+#: label -> device scope of each phase
+PHASE_SCOPES = {p: "fed." + p for p in PHASES}
+#: the reading of the busy time outside every scope of a set
+REST = "other"
 
 Interval = Tuple[float, float]
 
@@ -171,6 +194,141 @@ def reduce(pd) -> Optional[Reduction]:
                      op_ns=dict(op_ns), op_count=dict(op_n),
                      gaps=sorted(gaps.items(), key=lambda kv: -kv[1]),
                      devices=len(per_dev))
+
+
+# -------------------------------------------------------------- scopes
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s(.*)$")
+_COMP = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="((?:[^"\\]|\\.)*)"')
+_REF = re.compile(r"%([\w.\-]+)")
+_FUSES = re.compile(r"\bfusion\(.*\bcalls=%([\w.\-]+)")
+
+
+def _scope_finder(scopes: Dict[str, str]):
+    """op_name -> the label of its innermost scope of ``scopes`` (label
+    -> scope name), or None."""
+    label = {v: k for k, v in scopes.items()}
+    pattern = re.compile(r"(?:^|[/(])(" + "|".join(
+        re.escape(v) for v in scopes.values()) + r")(?=[/)]|$)")
+
+    def find(op_name: str) -> Optional[str]:
+        found = pattern.findall(op_name)
+        return label[found[-1]] if found else None
+    return find
+
+
+def phase_of(op_name: str, scopes: Dict[str, str] = PHASE_SCOPES
+             ) -> Optional[str]:
+    """The label of the innermost of ``scopes`` in an ``op_name``."""
+    return _scope_finder(scopes)(op_name)
+
+
+def hlo_phases(hlo_text: str, scopes: Dict[str, str] = PHASE_SCOPES
+               ) -> Dict[str, Optional[str]]:
+    """Instruction name -> label of its scope (None: none of
+    ``scopes``) of every instruction of a module's text.  An instruction
+    inside a loop, call or branch computation runs as part of the
+    instruction that calls it, and takes its scope where it names none
+    itself.  An instruction without an ``op_name`` takes, in order: for
+    a fusion, the scope of the root of the computation it fuses, else
+    the one scope its fused instructions name; the one scope its users
+    have (users first, so a chain of such instructions follows the op
+    it feeds); the one scope its operands have (a relayout of a scope's
+    result)."""
+    scope_of = _scope_finder(scopes)
+    own: Dict[str, Optional[str]] = {}
+    bare, order = set(), []
+    refs: Dict[str, List[str]] = {}
+    fuses: Dict[str, str] = {}
+    body: Dict[str, List[str]] = defaultdict(list)
+    root: Dict[str, str] = {}
+    comp = None
+    for line in hlo_text.splitlines():
+        m = _COMP.match(line)
+        if m and not line[0].isspace():
+            comp = m.group(1)
+            continue
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, rhs = m.groups()
+        op = _OP_NAME.search(rhs)
+        own[name] = scope_of(op.group(1)) if op else None
+        if not op:
+            bare.add(name)
+        order.append(name)
+        refs[name] = _REF.findall(rhs.split(" metadata=", 1)[0])
+        f = _FUSES.search(rhs)
+        if f:
+            fuses[name] = f.group(1)
+        body[comp].append(name)
+        if line.lstrip().startswith("ROOT"):
+            root[comp] = name
+    out = dict(own)
+    for name, called in fuses.items():
+        if name in bare:
+            inside = {own[i] for i in body[called]} - {None}
+            out[name] = own.get(root.get(called)) or (
+                inside.pop() if len(inside) == 1 else None)
+    caller = {c: name for name in order if name not in fuses
+              for c in refs[name] if c in body}
+    changed = True
+    while changed:
+        changed = False
+        for c, name in caller.items():
+            for i in body[c]:
+                if out[i] is None and out[name] is not None:
+                    out[i] = out[name]
+                    changed = True
+    users: Dict[str, set] = defaultdict(set)
+    for name in order:
+        for r in refs[name]:
+            users[r].add(name)
+    for name in reversed(order):
+        if name in bare and out[name] is None:
+            found = {out[u] for u in users[name]} - {None}
+            if len(found) == 1:
+                out[name] = found.pop()
+    for name in order:
+        if name in bare and out[name] is None:
+            found = {out[r] for r in refs[name] if r in out} - {None}
+            if len(found) == 1:
+                out[name] = found.pop()
+    return out
+
+
+def phase_ns(red: Optional[Reduction], mapping: Dict[str, Optional[str]],
+             labels=PHASES) -> Optional[Dict[str, float]]:
+    """Self time (ns, summed over devices) of each of ``labels``, and of
+    the rest (`REST`) as busy time less those; None where ``mapping``
+    names no scope."""
+    if red is None or not any(mapping.values()):
+        return None
+    out = dict.fromkeys(labels, 0.0)
+    for name, ns in red.op_ns.items():
+        ph = mapping.get(name)
+        if ph is not None:
+            out[ph] += ns
+    out[REST] = red.busy_ns * red.devices - sum(out.values())
+    return out
+
+
+_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+
+
+def without_metadata(hlo_text: str) -> str:
+    """A module's text without what names source and scopes: each
+    instruction's ``metadata={...}`` and the tables of files, functions
+    and stack frames that metadata points into."""
+    out, table = [], False
+    for line in hlo_text.splitlines():
+        if line in _TABLES:
+            table = True
+        if table:
+            table = bool(line.strip())
+            continue
+        out.append(re.sub(r",? metadata=\{[^}]*\}", "", line))
+    return "\n".join(out)
 
 
 def load(directory: str):

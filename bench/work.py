@@ -1,6 +1,7 @@
 """The work the algorithm does, counted from shapes: model FLOPs of a
-round, and the bytes each kernel launch moves, read from the operand
-and result shapes of the compiled round's custom calls."""
+round (from the model module's FLOPs per token), and the bytes each
+kernel launch moves, read from the operand and result shapes of the
+compiled round's custom calls."""
 from __future__ import annotations
 
 import json
@@ -25,40 +26,19 @@ FAMILIES = {
 _SHAPE = re.compile(r"\b(" + "|".join(ELEMENT_BYTES) + r")\[([0-9,]*)\]")
 
 
-def matmul_params(cfg) -> int:
-    """Parameters that take part in a matrix product per token: every
-    layer's attention and SwiGLU matrices, and the tied output head
-    (the embedding lookup is not a product)."""
-    D, F, L, V = (cfg["hidden_size"], cfg["intermediate_size"],
-                  cfg["num_hidden_layers"], cfg["vocab_size"])
-    hd = D // cfg["num_attention_heads"]
-    att = D * hd * (2 * cfg["num_attention_heads"]
-                    + 2 * cfg["num_key_value_heads"])
-    return L * (att + 3 * D * F) + V * D
-
-
-def train_flops_per_token(cfg, seq: int) -> float:
-    """Forward and backward FLOPs of one token at sequence length
-    ``seq``: 6 per matrix parameter, plus causal attention's scores and
-    weighted sum (2 x 2 x seq/2 x D per layer forward, times 3)."""
-    D = cfg["num_attention_heads"] * (cfg["hidden_size"]
-                                      // cfg["num_attention_heads"])
-    return (6.0 * matmul_params(cfg)
-            + 6.0 * cfg["num_hidden_layers"] * seq * D)
-
-
-def round_flops(cfg, traffic, rounds: int = 1, first_round: int = 0
-                ) -> float:
-    """Model FLOPs of ``rounds`` synchronous rounds: every client's
-    local steps, plus a forward and backward pass for each curvature
-    refresh (every ``tau``-th local step).  Recomputation is not
-    counted."""
+def round_flops(per_token: float, traffic, rounds: int = 1,
+                first_round: int = 0) -> float:
+    """Model FLOPs of ``rounds`` synchronous rounds, at ``per_token``
+    FLOPs for one token's forward and backward pass (the model module's
+    ``train_flops_per_token``): every client's local steps, plus a
+    forward and backward pass for each curvature refresh (every
+    ``tau``-th local step).  Recomputation is not counted."""
     J, tau = traffic["local_iters"], traffic["tau"]
     passes = 0
     for r in range(first_round, first_round + rounds):
         passes += J + sum((r * J + j) % tau == 0 for j in range(J))
     tokens = traffic["clients"] * traffic["batch"] * traffic["seq"]
-    return passes * tokens * train_flops_per_token(cfg, traffic["seq"])
+    return passes * tokens * per_token
 
 
 def shape_bytes(text: str) -> int:
